@@ -281,6 +281,9 @@ def family_sweep(*, smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--paged-sweep" in sys.argv:
         paged_sweep(smoke="--smoke" in sys.argv)
         family_sweep(smoke="--smoke" in sys.argv)

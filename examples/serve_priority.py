@@ -10,18 +10,16 @@ Run:  PYTHONPATH=src python examples/serve_priority.py
 
 import threading
 
-import jax
 import numpy as np
 
-from repro.configs.registry import get_config
-from repro.models import model as M
-from repro.serving.engine import ServeEngine, StreamSpec
+from repro.launch import serve
+from repro.serving.engine import StreamSpec
 
 
 def main() -> None:
-    cfg = get_config("internlm2_1_8b").reduced()
-    params = M.init_params(cfg, jax.random.PRNGKey(3))
-    engine = ServeEngine(cfg, params, max_seq=64, ordering="priority")
+    cfg, params = serve.init_model("internlm2_1_8b", reduced=True, seed=3)
+    # the unbatched server: one request per device call, priority-ordered
+    engine = serve.build_engine(cfg, params, serve.REDUCED, batching=False)
 
     assert engine.admit(StreamSpec("interactive", priority=10, period_ms=400,
                                    deadline_ms=400, prefill_ms=30,

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def pipeline_apply(stage_fn, params, x_mb, *, mesh, axis: str, out_like=None):
     """Run a GPipe pipeline over mesh axis ``axis``.
@@ -64,10 +62,8 @@ def pipeline_apply(stage_fn, params, x_mb, *, mesh, axis: str, out_like=None):
 
         state0 = jnp.zeros_like(xs[0])
         # the carry becomes rank-varying after the first ppermute: mark it
-        # so (pvary only exists once the varying-axes checker does, jax >=
-        # 0.6; older releases need no marking)
-        if hasattr(jax.lax, "pvary"):
-            state0 = jax.lax.pvary(state0, (axis,))
+        # so for the varying-axes checker
+        state0 = jax.lax.pcast(state0, (axis,), to="varying")
         _, emitted = jax.lax.scan(tick, state0, stream)
         # finished microbatch m leaves the last rank at tick m + P - 1
         outs = emitted[n_stages - 1:]
@@ -77,7 +73,7 @@ def pipeline_apply(stage_fn, params, x_mb, *, mesh, axis: str, out_like=None):
         return jax.lax.psum(outs * mask, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), params)
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),

@@ -23,12 +23,6 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 exposes shard_map at the top level; 0.4.x keeps it experimental.
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-
 _CTX = threading.local()
 
 

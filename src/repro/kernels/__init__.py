@@ -1,12 +1,7 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels with their pure-jnp references (``ref.py``).
 
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(dimension_semantics):
-    """CompilerParams across the pallas-TPU rename: jax 0.4.x calls it
-    TPUCompilerParams, newer releases CompilerParams."""
-    cls = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-    return cls(dimension_semantics=tuple(dimension_semantics))
+No model calls them yet: the served path runs the jnp/XLA versions.  Each
+kernel takes ``interpret=`` explicitly; tests run them in interpret mode on
+the CPU, and ``tests/test_tpu_compile.py`` compiles them for a described
+TPU topology.
+"""

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -116,7 +115,8 @@ def decode_attention(q, k, v, lengths, *, scale: float | None = None,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q[:, :, None, :], k, v)
     return out[:, :, 0, :]

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -103,6 +102,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.decode_attention import (online_softmax_block,
                                             online_softmax_finalize,
                                             online_softmax_init)
@@ -97,8 +96,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, 1, h), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q[:, :, None, :], k_pool, v_pool)
     return out[:, :, 0, :]
@@ -183,8 +182,8 @@ def paged_mla_decode_attention(q_lat, q_rope, ckv_pool, krope_pool,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, 1, r), q_lat.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q, ckv_pool, krope_pool)
     return out[:, :, 0, :]

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -87,7 +86,8 @@ def ssd_intra(x, dt, dA, B, C, *, interpret: bool = False):
             jax.ShapeDtypeStruct((m, h, q, p), x.dtype),
             jax.ShapeDtypeStruct((m, h, n, p), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, dA, B, C)
     return y, s
@@ -156,7 +156,8 @@ def ssd_slab_decode(state_pool, slab_ids, x, dt, A, B, C, *,
             jax.ShapeDtypeStruct((bsz, h, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(slab_ids, x, dt, A, Bh, Ch, state_pool)
     return y, states

@@ -223,7 +223,7 @@ def _moe_decode_tpdata(cfg, rules, p: Params, x):
     dp = (tuple(rules.batch_axes) if len(rules.batch_axes) > 1
           else rules.batch_axes[0])
     x_spec = P(batch, None, None)
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis, None, dp), P(axis, None, dp), P(axis, dp, None),
                   x_spec),
@@ -260,7 +260,7 @@ def moe_layer(cfg, p: Params, x):
                                       capacity=capacity, e_local=e // n, axis=axis)
                 return out.reshape(bb, ss, dd), jax.lax.pmean(aux, all_axes)
 
-            out, aux = shd.shard_map(
+            out, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(axis, None, None), P(batch, axis, None)),
                 out_specs=(P(batch, axis, None), P()),
@@ -284,7 +284,7 @@ def moe_layer(cfg, p: Params, x):
                 # over the DP axes, so average over those alone
                 return out.reshape(bb, ss, dd), jax.lax.pmean(aux, rules.batch_axes)
 
-            out, aux = shd.shard_map(
+            out, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(axis, None, None), P(batch, None, None)),
                 out_specs=(P(batch, None, None), P()),

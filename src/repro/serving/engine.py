@@ -2,7 +2,9 @@
 inference runtime — a multi-server pool with continuous, PAGED, length-aware
 decode batching.
 
-Architecture (one engine per host; one server per device / mesh slice):
+Architecture (one engine per host; server ``i`` runs on
+``jax.local_devices()[i % n]`` with its own copy of the parameters, its own
+cache pools and its own compiled step programs):
 
   client streams ──admit──▶ PoolAdmissionController (Eqs (1)-(6) per
         │                   device partition; device-assignment = WFD on
@@ -107,6 +109,17 @@ class PrecompileReport:
 
 
 @dataclass
+class _WarmCells:
+    """Shape cells compiled on one device.  Compiled programs are per
+    device, so each device keeps its own sets; servers that share a device
+    share them."""
+
+    decode: set = field(default_factory=set)  # (rows, width)
+    prefill: set = field(default_factory=set)  # (rows, bucket)
+    migrate: set = field(default_factory=set)  # width
+
+
+@dataclass
 class StreamSpec:
     name: str
     priority: int
@@ -125,6 +138,8 @@ class GenerationResult:
     prefill_latency_s: float = 0.0
     decode_latencies_s: list[float] = field(default_factory=list)
     recoveries: int = 0  # server deaths this job survived
+    # the prefill's greedy token: fed to decode step 0, not in ``tokens``
+    first_token: int | None = None
     # monotonic timestamp per recovery at which the retained prefix was
     # re-established on a survivor (resume point, for latency measurement)
     resumed_at_monotonic: list[float] = field(default_factory=list)
@@ -247,6 +262,11 @@ class ServeEngine:
         self._prefill_kind = "prefill" + _tag
         self._migrate_kind = "migrate" + _tag
         self.kv_block_size = kv_block_size
+        # server i -> local device i % n; ``params`` are copied to a device
+        # the first time a server there needs them (see _params_on)
+        self._devices = [self._device_for(i) for i in range(num_servers)]
+        self._placed: dict = {}
+        self._place_lock = threading.Lock()
         self.pool = ServerPool(num_servers, ordering=ordering,
                                batching=batching, max_batch=max_batch,
                                name="serve-engine")
@@ -287,6 +307,12 @@ class ServeEngine:
                                  mode="prefill"))
         self._decode = jax.jit(
             lambda p, b, c: M.apply(cfg, p, b, mode="decode", cache=c))
+        # each prefill row's logits at its true last position — jitted so
+        # the pick compiles once per prefill cell (with it, in precompile)
+        # rather than as an eager gather per live-row count mid-traffic
+        self._last_logits = jax.jit(
+            lambda logits, lens: jnp.take_along_axis(
+                logits, (lens - 1)[:, None, None], axis=1)[:, 0])
         self._streams: dict[str, StreamSpec] = {}
         # shape-bucket boundaries (tunable via tune_buckets()): batch rows
         # and prefill pad lengths default to the full pow2 ladder — exactly
@@ -294,11 +320,9 @@ class ServeEngine:
         self._row_buckets = _pow2_ladder(max_batch)
         self.prefill_buckets = _pow2_ladder(max_seq)
         self.width_buckets: tuple[int, ...] = ()
-        # cells warmed by precompile(); consulted by the safe-fallback
-        # bump-up in the hot path (engine-level: the jitted step callables
-        # are shared across servers, so one trace warms the whole pool)
-        self._warm_decode: set[tuple[int, int]] = set()
-        self._warm_prefill: set[tuple[int, int]] = set()
+        # cells warmed by precompile(), per device; consulted by the
+        # safe-fallback bump-up in the hot path
+        self._warm: dict = {}
         if batching:
             self._slots = [_SlotState(max_batch) for _ in range(num_servers)]
             self._batch_axes = _cache_batch_axes(cfg, max_seq)
@@ -347,7 +371,38 @@ class ServeEngine:
             self._export_kv = jax.jit(self._export_kv_impl)
             self._import_kv = jax.jit(self._import_kv_impl,
                                       donate_argnums=(0,))
-            self._warm_migrate: set[int] = set()
+
+    @staticmethod
+    def _device_for(si: int):
+        local = jax.local_devices()
+        return local[si % len(local)]
+
+    def device_of(self, si: int):
+        """The device server ``si`` runs on."""
+        return self._devices[si]
+
+    def _params_on(self, si: int):
+        """``params`` committed to server ``si``'s device (one copy per
+        device, made on first use)."""
+        dev = self._devices[si]
+        with self._place_lock:
+            placed = self._placed.get(dev)
+            if placed is None:
+                placed = self._placed[dev] = jax.device_put(self.params, dev)
+        return placed
+
+    def _put(self, si: int, tree):
+        """Host staging arrays -> server ``si``'s device."""
+        return jax.device_put(tree, self._devices[si])
+
+    def _zeros_on(self, si: int, make):
+        """A zero cache pytree built on server ``si``'s device."""
+        dev = self._devices[si]
+        with jax.default_device(dev):
+            return jax.device_put(make(), dev)
+
+    def _warm_of(self, si: int) -> _WarmCells:
+        return self._warm.setdefault(self._devices[si], _WarmCells())
 
     @property
     def server(self):
@@ -450,28 +505,104 @@ class ServeEngine:
             self.width_buckets = autotune_buckets(
                 needs, _pow2_ladder(nb_max), max_buckets=max_buckets,
                 cost_of=wmodel)
-        self._warm_decode.clear()
-        self._warm_prefill.clear()
+        for warm in self._warm.values():
+            warm.decode.clear()
+            warm.prefill.clear()
         return self.prefill_buckets, self.width_buckets
 
-    # -- static cell pricing (hlo_cost -> cost-model features) -------------
-    def static_cell_costs(self, cells=None) -> dict:
-        """Price shape cells STATICALLY: compile each cell's trace (no
-        device execution) and walk the optimized HLO with
-        ``analysis.hlo_cost`` for exact per-cell (flops, hbm_bytes).
-        Returns {CellKey: (flops, hbm_bytes)} ready for
-        ``cost_model.hlo_cell_features`` — the feed that lets a
-        ``StepCostModel`` price a migration/scatter width (or any cell) it
-        never measured at runtime off static analysis instead of the
-        declared worst case.
+    def traffic_cells(self, requests, *, concurrency: int) -> set:
+        """The shape cells a known workload can hit: ``requests`` are
+        (prompt_length, decode_steps) pairs, at most ``concurrency`` of them
+        in flight on one server at a time.  Pass the result as
+        ``precompile(traffic=...)`` (after any ``tune_buckets``) to compile
+        exactly these cells plus each phase's fallback."""
+        rows = {bucket_up(n, self._row_buckets)
+                for n in range(1, concurrency + 1)}
+        bs = self.kv_block_size
+        nb_max = self._paged[0].nb_max if self.paged else 0
+        cells = set()
+        for length, steps in requests:
+            bucket = bucket_up(length, self.prefill_buckets)
+            cells |= {(self._prefill_kind, r, bucket) for r in rows}
+            if not self.paged:
+                continue
+            for pos in range(length, length + steps):
+                need = -(-(pos + 1) // bs) if nb_max else 0
+                w = bucket_up(need, self.width_buckets)
+                cells |= {(self._decode_kind, r, w) for r in rows}
+        return cells
 
-        ``cells`` is an iterable of CellKeys (``("decode", rows, width)``,
-        ``("prefill", rows, bucket)``, ``("migrate", width, block_size)``);
-        default: every migrate width bucket — the cells a steal can hit
-        cold.  Compilation reuses XLA's jit cache, so cells already warm
-        from precompile()/traffic cost only the HLO walk.  Paged engines
-        only (the masked-dense decode has a single full-shape cell that
-        measurement always covers)."""
+    # -- static cell pricing (hlo_cost -> cost-model features) -------------
+    def lower_cells(self, cells, *, sharding=None) -> dict:
+        """Lower each cell's step programs from shapes alone (no device
+        execution, no parameter placement).  Returns {CellKey: [Lowered,
+        ...]}: ``("decode", rows, width)`` -> the paged decode step;
+        ``("prefill", rows, bucket)`` -> the bucketed prefill;
+        ``("insert", rows, bucket)`` -> the scatter of such a prefill's
+        cache into the pools; ``("migrate", width, block_size)`` -> the
+        gather and the scatter of one migration.  ``sharding`` places every
+        argument (e.g. a device of a described TPU topology, to compile for
+        a chip that is not attached); default: the default device.  Paged
+        engines only."""
+        if not self.paged:
+            raise ValueError("lower_cells requires paged=True")
+
+        def spec(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        def specs(tree):
+            return jax.tree.map(spec, tree)
+
+        params = specs(self.params)
+        pools = specs(jax.eval_shape(
+            lambda: M.init_paged_cache(self.cfg, self._num_blocks,
+                                       self.kv_block_size,
+                                       num_slabs=self._num_slabs,
+                                       num_segments=self._num_segments)))
+        idx = spec(jax.ShapeDtypeStruct((), jnp.int32))
+        out: dict[tuple, list] = {}
+        for cell in cells:
+            phase, a, b = cell
+            base = phase.split("@", 1)[0]  # family-tagged phases lower alike
+            if base == "migrate":
+                table = spec(jax.ShapeDtypeStruct((a,), jnp.int32))
+                packed = specs(jax.eval_shape(self._export_kv_impl, pools,
+                                              table, idx, idx))
+                out[cell] = [
+                    self._export_kv.lower(pools, table, idx, idx),
+                    self._import_kv.lower(pools, packed, table, idx, idx)]
+            elif base == "decode":
+                packed = spec(jax.ShapeDtypeStruct((a, 4 + b), jnp.int32))
+                out[cell] = [self._decode_paged.lower(params, packed, pools)]
+            elif base in ("prefill", "insert"):
+                batch = specs(self._prefill_batch(np.zeros((a, b), np.int32),
+                                                  np.ones((a,), np.int32)))
+                if base == "prefill":
+                    out[cell] = [self._prefill.lower(params, batch)]
+                    continue
+                cache = specs(jax.eval_shape(self._prefill, params,
+                                             batch)[1])
+                table = spec(jax.ShapeDtypeStruct((self._paged[0].nb_max,),
+                                                  jnp.int32))
+                out[cell] = [self._insert_paged_jit.lower(
+                    pools, cache, idx, table, idx, idx)]
+            else:
+                raise ValueError(f"unknown phase in cell {cell!r}")
+        return out
+
+    def static_cell_costs(self, cells=None) -> dict:
+        """Price shape cells STATICALLY: compile each cell's programs (see
+        ``lower_cells``; no device execution) and walk the optimized HLO
+        with ``analysis.hlo_cost`` for exact per-cell (flops, hbm_bytes),
+        summed over the cell's programs.  Returns {CellKey: (flops,
+        hbm_bytes)} ready for ``cost_model.hlo_cell_features`` — the feed
+        that lets a ``StepCostModel`` price a migration/scatter width (or
+        any cell) it never measured at runtime off static analysis instead
+        of the declared worst case.
+
+        ``cells`` default: every migrate width bucket — the cells a steal
+        can hit cold.  Paged engines only (the masked-dense decode has a
+        single full-shape cell that measurement always covers)."""
         from repro.analysis import hlo_cost
 
         if not self.paged:
@@ -479,40 +610,12 @@ class ServeEngine:
         if cells is None:
             cells = [(self._migrate_kind, w, self.kv_block_size)
                      for w in self.width_buckets]
-        pools = jax.eval_shape(
-            lambda: M.init_paged_cache(self.cfg, self._num_blocks,
-                                       self.kv_block_size,
-                                       num_slabs=self._num_slabs,
-                                       num_segments=self._num_segments))
-
-        def cost_of(lowered) -> tuple[float, float]:
-            c = hlo_cost.analyze_text(lowered.compile().as_text())
-            return (c.flops, c.hbm_bytes)
-
         out: dict[tuple, tuple[float, float]] = {}
-        idx = jax.ShapeDtypeStruct((), jnp.int32)
-        for cell in cells:
-            phase, a, b = cell
-            base = phase.split("@", 1)[0]  # family-tagged phases price alike
-            if base == "migrate":
-                table = jax.ShapeDtypeStruct((a,), jnp.int32)
-                packed = jax.eval_shape(self._export_kv_impl, pools, table,
-                                        idx, idx)
-                fg, bg = cost_of(self._export_kv.lower(pools, table, idx,
-                                                       idx))
-                fs, bs = cost_of(self._import_kv.lower(pools, packed,
-                                                       table, idx, idx))
-                out[cell] = (fg + fs, bg + bs)
-            elif base == "decode":
-                packed = jax.ShapeDtypeStruct((a, 4 + b), jnp.int32)
-                out[cell] = cost_of(
-                    self._decode_paged.lower(self.params, packed, pools))
-            elif base == "prefill":
-                batch = self._prefill_batch(np.zeros((a, b), np.int32))
-                batch["lengths"] = jnp.ones((a,), jnp.int32)
-                out[cell] = cost_of(self._prefill.lower(self.params, batch))
-            else:
-                raise ValueError(f"unknown phase in cell {cell!r}")
+        for cell, programs in self.lower_cells(cells).items():
+            costs = [hlo_cost.analyze_text(lo.compile().as_text())
+                     for lo in programs]
+            out[cell] = (sum(c.flops for c in costs),
+                         sum(c.hbm_bytes for c in costs))
         return out
 
     # -- batched decode internals (masked-dense layout) --------------------
@@ -568,10 +671,10 @@ class ServeEngine:
         """Runs on server ``si``'s thread (serialized with its batches)."""
         state = self._slots[si]
         if state.cache is None:
-            state.cache = M.init_cache(self.cfg, self.max_batch, self.max_seq)
+            state.cache = self._make_slot_cache(si)
+        src_row, slot = self._put(si, (np.int32(src_row), np.int32(slot)))
         state.cache = jax.block_until_ready(
-            self._insert_jit(state.cache, cache, jnp.int32(src_row),
-                             jnp.int32(slot)))
+            self._insert_jit(state.cache, cache, src_row, slot))
 
     def _run_decode_batch(self, si: int):
         """run_batch callable for server ``si`` (masked-dense): payloads are
@@ -587,20 +690,25 @@ class ServeEngine:
             for slot, token in payloads:
                 toks[slot, 0] = token
                 active[slot] = True
+            toks_d, active_d = self._put(si, (toks, active))
             logits, state.cache = jax.block_until_ready(
-                self._decode_masked(self.params, jnp.asarray(toks),
-                                    state.cache, jnp.asarray(active)))
+                self._decode_masked(self._params_on(si), toks_d,
+                                    state.cache, active_d))
             rows = np.asarray(logits[:, -1], np.float32)
             return [rows[slot] for slot, _ in payloads]
 
         return run
 
     # -- batched decode internals (paged pool layouts, family-generic) -----
-    def _make_pools(self, state):
-        return M.init_paged_cache(self.cfg, state.mgr.num_blocks,
-                                  state.mgr.block_size,
-                                  num_slabs=state.mgr.num_slabs,
-                                  num_segments=state.mgr.num_segments)
+    def _make_slot_cache(self, si: int):
+        return self._zeros_on(si, lambda: M.init_cache(
+            self.cfg, self.max_batch, self.max_seq))
+
+    def _make_pools(self, si: int):
+        mgr = self._paged[si].mgr
+        return self._zeros_on(si, lambda: M.init_paged_cache(
+            self.cfg, mgr.num_blocks, mgr.block_size,
+            num_slabs=mgr.num_slabs, num_segments=mgr.num_segments))
 
     def _insert_paged_impl(self, pools, cache, src_row, table, slab, seg):
         """Scatter row ``src_row`` of a prefill cache into the pools,
@@ -663,11 +771,11 @@ class ServeEngine:
         """Runs on server ``si``'s thread (serialized with its batches)."""
         state = self._paged[si]
         if state.pools is None:
-            state.pools = self._make_pools(state)
+            state.pools = self._make_pools(si)
+        args = self._put(si, (np.int32(src_row), table, np.int32(slab),
+                              np.int32(seg)))
         state.pools = jax.block_until_ready(
-            self._insert_paged_jit(state.pools, cache, jnp.int32(src_row),
-                                   jnp.asarray(table), jnp.int32(slab),
-                                   jnp.int32(seg)))
+            self._insert_paged_jit(state.pools, cache, *args))
 
     def _run_paged_decode(self, si: int):
         """run_batch callable for server ``si`` (paged): payloads are
@@ -694,9 +802,9 @@ class ServeEngine:
             # all-zero scratch block past each row's length, extra rows
             # duplicate row 0 idempotently).  No warm cover -> compile cold.
             cold = False
-            if self._warm_decode and (n_pad, w) not in self._warm_decode:
-                covers = [c for c in self._warm_decode
-                          if c[0] >= n_pad and c[1] >= w]
+            warm = self._warm_of(si).decode
+            if warm and (n_pad, w) not in warm:
+                covers = [c for c in warm if c[0] >= n_pad and c[1] >= w]
                 if covers:
                     n_pad, w = min(covers, key=lambda c: c[0] * c[1])
                 else:
@@ -712,12 +820,12 @@ class ServeEngine:
                 pack[i] = pack[0]
             t0 = time.monotonic()
             logits, state.pools = jax.block_until_ready(
-                self._decode_paged(self.params,
-                                   jnp.asarray(pack[:n_pad, : 4 + w]),
+                self._decode_paged(self._params_on(si),
+                                   self._put(si, pack[:n_pad, : 4 + w]),
                                    state.pools))
             dt = time.monotonic() - t0
             if cold:  # now traced: later hits on this cell are warm
-                self._warm_decode.add((n_pad, w))
+                warm.add((n_pad, w))
             self.pool.servers[si].record_meta(
                 kind=self._decode_kind, rows=n, padded=n_pad, width=w,
                 compacted=n_pad < self.max_batch, seconds=dt, cold=cold)
@@ -811,13 +919,17 @@ class ServeEngine:
             out[key] = jax.tree.map(fn, pools[key], packed[key])
         return out
 
-    def _migrate_cell(self, n_blocks: int) -> tuple[int, bool]:
+    def _migrate_cell(self, n_blocks: int, src_si: int,
+                      dst_si: int) -> tuple[int, bool]:
         """(padded gather width, cold?) for a migration of ``n_blocks`` —
-        same warm-cell bump-up discipline as the decode hot path."""
+        same warm-cell bump-up discipline as the decode hot path, over the
+        widths warm on BOTH devices (gather on the source, scatter on the
+        destination)."""
         w = bucket_up(n_blocks, self.width_buckets)
+        warm = self._warm_of(src_si).migrate & self._warm_of(dst_si).migrate
         cold = False
-        if self._warm_migrate and w not in self._warm_migrate:
-            covers = [c for c in self._warm_migrate if c >= w]
+        if warm and w not in warm:
+            covers = [c for c in warm if c >= w]
             if covers:
                 w = min(covers)
             else:
@@ -874,7 +986,7 @@ class ServeEngine:
             held.add((dst_si, seq_id))
         try:
             n = len(exp.blocks)
-            w, cold = self._migrate_cell(n)
+            w, cold = self._migrate_cell(n, src_si, dst_si)
             src_table = np.full((w,), src.scratch_block, np.int32)
             src_table[:n] = exp.blocks
             dst_table = np.full((w,), dst.scratch_block, np.int32)
@@ -882,10 +994,10 @@ class ServeEngine:
 
             def gather():
                 t0 = time.monotonic()
+                args = self._put(src_si, (src_table, np.int32(src_slab),
+                                          np.int32(src_seg)))
                 packed = jax.block_until_ready(
-                    self._export_kv(src.pools, jnp.asarray(src_table),
-                                    jnp.int32(src_slab),
-                                    jnp.int32(src_seg)))
+                    self._export_kv(src.pools, *args))
                 packed = jax.tree.map(np.asarray, packed)  # device -> host
                 self.pool.servers[src_si].record_meta(
                     kind=self._migrate_kind, rows=n, padded=w,
@@ -898,14 +1010,13 @@ class ServeEngine:
 
             def scatter():
                 if dst.pools is None:
-                    dst.pools = self._make_pools(dst)
+                    dst.pools = self._make_pools(dst_si)
                 t0 = time.monotonic()
+                args = self._put(dst_si, (packed, dst_table,
+                                          np.int32(dst_slab),
+                                          np.int32(dst_seg)))
                 dst.pools = jax.block_until_ready(
-                    self._import_kv(dst.pools,
-                                    jax.tree.map(jnp.asarray, packed),
-                                    jnp.asarray(dst_table),
-                                    jnp.int32(dst_slab),
-                                    jnp.int32(dst_seg)))
+                    self._import_kv(dst.pools, *args))
                 self.pool.servers[dst_si].record_meta(
                     kind=self._migrate_kind, rows=n, padded=w,
                     width=self.kv_block_size,
@@ -949,9 +1060,9 @@ class ServeEngine:
             # steered to a warm pad length by _generate_batched): padding
             # rows duplicate row 0 and their outputs are discarded
             cold = False
-            if self._warm_prefill and (n_pad, bucket) not in self._warm_prefill:
-                covers = [r for r, b in self._warm_prefill
-                          if b == bucket and r >= n_pad]
+            warm = self._warm_of(si).prefill
+            if warm and (n_pad, bucket) not in warm:
+                covers = [r for r, b in warm if b == bucket and r >= n_pad]
                 if covers:
                     n_pad = min(covers)
                 else:
@@ -964,18 +1075,18 @@ class ServeEngine:
             for i in range(n, n_pad):  # padding rows: discarded outputs
                 toks[i] = toks[0]
                 lens[i] = lens[0]
-            batch = self._prefill_batch(toks)
-            batch["lengths"] = jnp.asarray(lens)
+            batch = self._put(si, self._prefill_batch(toks, lens))
             t0 = time.monotonic()
             logits, cache, _ = jax.block_until_ready(
-                self._prefill(self.params, batch))
+                self._prefill(self._params_on(si), batch))
             dt = time.monotonic() - t0
             if cold:
-                self._warm_prefill.add((n_pad, bucket))
+                warm.add((n_pad, bucket))
             self.pool.servers[si].record_meta(
                 kind=self._prefill_kind, rows=n, padded=n_pad, bucket=bucket,
                 seconds=dt, cold=cold)
-            rows = np.asarray(logits[np.arange(n), lens[:n] - 1], np.float32)
+            rows = np.asarray(self._last_logits(logits, batch["lengths"]),
+                              np.float32)
             return [(rows[i], cache, i) for i in range(n)]
 
         return run
@@ -995,11 +1106,12 @@ class ServeEngine:
         ``cost_model.TrafficModel`` or an iterable of CellKeys — restricts
         compilation to the predicted-hit cells PLUS, always, the largest
         cell on each phase: the safe-fallback target the hot path bumps
-        cold cells up to (see _run_paged_decode).  Each distinct cell is
-        traced ONCE (the jitted step callables are shared across servers);
-        cells already warm from an earlier call are skipped, and the report
-        says how many traces were skipped vs compiled.  Pools / slot caches
-        are still created on every server.  No-op unless batching."""
+        cold cells up to (see _run_paged_decode).  Compiled programs are
+        per device, so each distinct cell is traced ONCE PER DEVICE, on the
+        first server placed there, and the devices compile concurrently;
+        cells already warm on a device are skipped, and the report sums
+        compiled vs skipped traces over devices.  Pools / slot caches are
+        still created on every server.  No-op unless batching."""
         if not self.batching:
             return PrecompileReport()
         hot = None
@@ -1018,7 +1130,6 @@ class ServeEngine:
         plan_d = [c for c in reachable_d
                   if hot is None or c == fb_d
                   or (self._decode_kind, *c) in hot]
-        todo_d = [c for c in plan_d if c not in self._warm_decode]
         buckets = sorted({bucket_up(b, self.prefill_buckets)
                           for b in prompt_buckets})
         reachable_p = [(r, b) for b in buckets for r in rows_ladder]
@@ -1026,7 +1137,6 @@ class ServeEngine:
         plan_p = [c for c in reachable_p
                   if hot is None or c == fb_p
                   or (self._prefill_kind, *c) in hot]
-        todo_p = [c for c in plan_p if c not in self._warm_prefill]
         # migration gather/scatter cells: one per width bucket (the traces
         # are cheap — pure gather/scatter, no model math), so a mid-traffic
         # steal never stalls a server behind XLA compilation
@@ -1035,37 +1145,50 @@ class ServeEngine:
         plan_m = [w for w in reachable_m
                   if hot is None or w == fb_m
                   or (self._migrate_kind, w, self.kv_block_size) in hot]
-        todo_m = [w for w in plan_m if w not in self._warm_migrate]
-        for si in range(len(self.pool.servers)):
-            # traces are shared: run the compile plan on server 0 only;
-            # the other servers just get their pools/caches initialized
-            d = todo_d if si == 0 else []
-            p = todo_p if si == 0 else []
-            m = todo_m if si == 0 else []
-            self.pool.servers[si].submit(
+        todo: dict = {}  # device -> (decode, prefill, migrate) cells
+        reqs = []
+        for si in self.pool.alive_servers():
+            dev = self._devices[si]
+            if dev in todo:  # device already planned: pools only
+                d = p = m = []
+            else:
+                warm = self._warm_of(si)
+                d = [c for c in plan_d if c not in warm.decode]
+                p = [c for c in plan_p if c not in warm.prefill]
+                m = [w for w in plan_m if w not in warm.migrate]
+                todo[dev] = (d, p, m)
+            reqs.append(self.pool.servers[si].submit(
                 lambda si=si, d=d, p=p, m=m:
                     self._precompile_server(si, d, p, m),
-                name=f"precompile-{si}").wait()
-        self._warm_decode.update(todo_d)
-        self._warm_prefill.update(todo_p)
-        if self.paged:
-            self._warm_migrate.update(todo_m)
-        skipped = ((len(reachable_d) - len(todo_d))
-                   + (len(reachable_p) - len(todo_p))
-                   + (len(reachable_m) - len(todo_m)))
-        return PrecompileReport(compiled=len(todo_d) + len(todo_p)
-                                + len(todo_m),
-                                skipped=skipped,
-                                decode_cells=tuple(todo_d),
-                                prefill_cells=tuple(todo_p),
-                                migrate_cells=tuple(todo_m))
+                name=f"precompile-{si}"))
+        for req in reqs:
+            req.wait()
+        for dev, (d, p, m) in todo.items():
+            warm = self._warm.setdefault(dev, _WarmCells())
+            warm.decode.update(d)
+            warm.prefill.update(p)
+            warm.migrate.update(m)
+        reachable = len(reachable_d) + len(reachable_p) + len(reachable_m)
+        compiled = sum(len(d) + len(p) + len(m)
+                       for d, p, m in todo.values())
+
+        def union(k, plan):
+            return tuple(c for c in plan
+                         if any(c in t[k] for t in todo.values()))
+
+        return PrecompileReport(compiled=compiled,
+                                skipped=reachable * len(todo) - compiled,
+                                decode_cells=union(0, plan_d),
+                                prefill_cells=union(1, plan_p),
+                                migrate_cells=union(2, plan_m))
 
     def _precompile_server(self, si: int, decode_cells, prefill_cells,
                            migrate_cells=()):
         if self.paged:
             state = self._paged[si]
             if state.pools is None:
-                state.pools = self._make_pools(state)
+                state.pools = self._make_pools(si)
+            params = self._params_on(si)
             for rows, w in decode_cells:
                 # dummy batch: every row scatters token 0 at offset 0 of
                 # the scratch block/slab (idempotent duplicates; the
@@ -1075,34 +1198,36 @@ class ServeEngine:
                 pack[:, 3] = state.scratch_seg
                 pack[:, 4:] = state.scratch_block
                 _, state.pools = jax.block_until_ready(
-                    self._decode_paged(self.params, jnp.asarray(pack),
+                    self._decode_paged(params, self._put(si, pack),
                                        state.pools))
             for w in migrate_cells:
                 # round-trip the scratch resources through gather +
                 # scatter: identical content lands back where it came from
-                table = jnp.full((w,), state.scratch_block, jnp.int32)
-                slab = jnp.int32(state.scratch_slab)
-                seg = jnp.int32(state.scratch_seg)
+                args = self._put(si, (
+                    np.full((w,), state.scratch_block, np.int32),
+                    np.int32(state.scratch_slab),
+                    np.int32(state.scratch_seg)))
                 packed = jax.block_until_ready(
-                    self._export_kv(state.pools, table, slab, seg))
+                    self._export_kv(state.pools, *args))
                 state.pools = jax.block_until_ready(
-                    self._import_kv(state.pools, packed, table, slab, seg))
+                    self._import_kv(state.pools, packed, *args))
         else:
             state = self._slots[si]
             if state.cache is None:
-                state.cache = M.init_cache(self.cfg, self.max_batch,
-                                           self.max_seq)
+                state.cache = self._make_slot_cache(si)
             for _cell in decode_cells:
-                toks = jnp.zeros((self.max_batch, 1), jnp.int32)
-                active = jnp.zeros((self.max_batch,), bool)  # all-masked
+                toks, active = self._put(si, (
+                    np.zeros((self.max_batch, 1), np.int32),
+                    np.zeros((self.max_batch,), bool)))  # all-masked
                 _, state.cache = jax.block_until_ready(
-                    self._decode_masked(self.params, toks, state.cache,
-                                        active))
+                    self._decode_masked(self._params_on(si), toks,
+                                        state.cache, active))
         for rows, bucket in prefill_cells:
-            batch = self._prefill_batch(np.zeros((rows, bucket), np.int32))
-            batch["lengths"] = jnp.ones((rows,), jnp.int32)
-            _, cache, _ = jax.block_until_ready(
-                self._prefill(self.params, batch))
+            batch = self._put(si, self._prefill_batch(
+                np.zeros((rows, bucket), np.int32), np.ones((rows,))))
+            logits, cache, _ = self._prefill(self._params_on(si), batch)
+            jax.block_until_ready(
+                self._last_logits(logits, batch["lengths"]))
             if self.paged:
                 state = self._paged[si]
                 table = np.full((state.nb_max,), state.scratch_block,
@@ -1124,27 +1249,30 @@ class ServeEngine:
         spec = self._streams[name]
         prio = self.straggler.boost(name, spec.priority)
         res = GenerationResult()
-        batch = self._prefill_batch(prompt)
+        si = self.pool.server_of(name)
+        params = self._params_on(si)
+        batch = self._put(si, self._prefill_batch(prompt))
 
         seq_id = self._kv_reserve(name, prompt, steps)
         try:
             t0 = time.monotonic()
             req = self.pool.submit(
                 name,
-                lambda: jax.block_until_ready(self._prefill(self.params, batch)),
+                lambda: jax.block_until_ready(self._prefill(params, batch)),
                 priority=prio, name=f"{name}/prefill")
             logits, cache, _ = req.wait()
             res.prefill_latency_s = time.monotonic() - t0
             self.straggler.observe(name, res.prefill_latency_s * 1e3)
 
             last = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            res.first_token = int(last[0])
             for i in range(steps):
                 step_batch = {"tokens": last[:, None]}
                 t1 = time.monotonic()
                 req = self.pool.submit(
                     name,
                     lambda sb=step_batch, c=cache: jax.block_until_ready(
-                        self._decode(self.params, sb, c)),
+                        self._decode(params, sb, c)),
                     priority=prio, name=f"{name}/decode{i}")
                 logits, cache, _ = req.wait()
                 dt = time.monotonic() - t1
@@ -1229,13 +1357,13 @@ class ServeEngine:
         append_first = log.first_token is not None
         feeds = steps - len(res.tokens) - (1 if append_first else 0)
         bucket = bucket_up(true_len, self.prefill_buckets)
-        if self._warm_prefill:
+        warm_prefill = self._warm_of(si).prefill
+        if warm_prefill:
             # traffic-aware precompile warmed a subset of pad lengths:
             # steer to the smallest warm bucket that fits rather than cold-
             # compiling the tight one (padding tokens' KV lands in owned
             # blocks; per-row true lengths mask them out of attention)
-            warm = sorted({b for _r, b in self._warm_prefill
-                           if b >= true_len})
+            warm = sorted({b for _r, b in warm_prefill if b >= true_len})
             if warm:
                 bucket = warm[0]
 
@@ -1282,7 +1410,7 @@ class ServeEngine:
                     res.tokens.append(token)
                     log.generated.append(token)
                 else:
-                    log.first_token = token
+                    log.first_token = res.first_token = token
                 length = true_len
                 run_batch = (self._run_paged_decode(si) if self.paged
                              else self._run_decode_batch(si))
@@ -1349,12 +1477,17 @@ class ServeEngine:
                 self._kv_release(seq_id)
 
     # -- shared helpers -----------------------------------------------------
-    def _prefill_batch(self, prompt: np.ndarray) -> dict:
+    def _prefill_batch(self, prompt: np.ndarray, lengths=None) -> dict:
+        """Host-side prefill inputs (the caller places them on a device):
+        ``lengths`` are per-row true lengths of a bucket-padded batch."""
         b = prompt.shape[0]
-        batch = {"tokens": jnp.asarray(prompt, jnp.int32)}
+        batch = {"tokens": np.asarray(prompt, np.int32)}
+        if lengths is not None:
+            batch["lengths"] = np.asarray(lengths, np.int32)
         if self.cfg.family == "encdec":
-            batch["frames"] = jnp.zeros(
-                (b, self.cfg.encoder_seq, self.cfg.d_model), self.cfg.dtype)
+            batch["frames"] = np.zeros(
+                (b, self.cfg.encoder_seq, self.cfg.d_model),
+                jnp.dtype(self.cfg.dtype))
         return batch
 
     def _kv_reserve(self, name: str, prompt: np.ndarray, steps: int):
@@ -1645,14 +1778,15 @@ class ServeEngine:
     def add_server(self) -> int:
         """Elastic scale-up: grow the pool AND the admission partition by
         one device mid-traffic; returns the new server index.  The server
-        inherits the pool's fault-tolerance settings (retry budget,
-        watchdog, heartbeat wiring — the pool handles the monitor), gets
-        its own slot/paged state, and warms its pools on its own thread —
-        the jitted shape cells are shared engine-wide, so no new XLA
-        traces happen; a freshly-joined server serves its first request at
-        full speed."""
+        is placed on ``jax.local_devices()[si % n]``, inherits the pool's
+        fault-tolerance settings (retry budget, watchdog, heartbeat wiring
+        — the pool handles the monitor), gets its own slot/paged state, and
+        warms its pools on its own thread.  On a device no earlier server
+        used it first compiles the cells warm on server 0's device, so a
+        freshly-joined server serves its first request at full speed."""
         with self._recovery_lock:
             si = self.pool.add_server()
+            self._devices.append(self._device_for(si))
             di = self.admission.add_device()
             if si != di:
                 raise RuntimeError(
@@ -1672,8 +1806,19 @@ class ServeEngine:
                 s.retry_backoff_s = self._ft_params["retry_backoff_s"]
                 if self._ft_params["watchdog"] and s.watchdog is None:
                     s.watchdog = StepTimeWatchdog()
-        s.submit(lambda: self._precompile_server(si, [], [], []),
+        warm = self._warm_of(si)
+        plan = _WarmCells()
+        if not (warm.decode or warm.prefill or warm.migrate):
+            src = self._warm.get(self._devices[0], _WarmCells())
+            plan = _WarmCells(set(src.decode), set(src.prefill),
+                              set(src.migrate))
+        s.submit(lambda: self._precompile_server(
+                     si, sorted(plan.decode), sorted(plan.prefill),
+                     sorted(plan.migrate)),
                  name=f"precompile-{si}").wait()
+        warm.decode.update(plan.decode)
+        warm.prefill.update(plan.prefill)
+        warm.migrate.update(plan.migrate)
         return si
 
     def remove_server(self, si: int, *, timeout_s: float = 10.0) -> None:

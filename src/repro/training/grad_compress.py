@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def quantize_int8(x):
     """x fp -> (q int8, scale fp32).  Symmetric per-tensor scaling."""
@@ -72,7 +70,7 @@ def compressed_psum_mean(grads, err, mesh, axes: tuple[str, ...]):
     # treat every leaf as fully local per shard on `axes`; other mesh axes
     # pass through unsharded specs (caller reshards around this op)
     specs = jax.tree.map(lambda _: P(), grads)
-    out = shard_map(body, mesh=mesh, in_specs=(specs, specs),
+    out = jax.shard_map(body, mesh=mesh, in_specs=(specs, specs),
                         out_specs=jax.tree.map(lambda _: (P(), P()), grads))
     pairs = out(grads, err)
     mean = jax.tree.map(lambda t: t[0], pairs,
