@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(averaged over the chips used).  At a fixed admitted load, faster steps
+raise it: that is a gain, not a loss."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

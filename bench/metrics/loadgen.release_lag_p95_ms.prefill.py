@@ -1,0 +1,7 @@
+"""``loadgen.release_lag_p95_ms``, in the cells whose tail is ``response_p95_ms``."""
+
+from pathlib import Path
+
+from metrics_io import load_reader
+
+read = load_reader(Path(__file__).parent, "loadgen.release_lag_p95_ms")

@@ -1,0 +1,9 @@
+"""Median over every job released in the window of its response (due
+release to the return of its ``generate`` call) over the tokens it
+served: how fast a stream's tokens come, waits included."""
+
+from metrics_io import pct
+
+
+def read(run):
+    return pct(run.ms_per_token(), 50)
