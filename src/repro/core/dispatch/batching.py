@@ -86,6 +86,8 @@ class BatchingServer(AcceleratorServer):
         priority: int = 0,
         deadline: float | None = None,
         name: str = "",
+        job: int = 0,
+        phase: str = "",
     ) -> BatchRequest:
         """Submit a batchable request; returns a waitable Request whose
         result is ``run_batch(payloads)[i]`` for this request's position in
@@ -94,7 +96,8 @@ class BatchingServer(AcceleratorServer):
             raise ValueError("batch_key must be hashable and non-None")
         return self._enqueue(
             BatchRequest(fn=None, priority=priority, deadline=deadline,
-                         name=name, batch_key=batch_key, payload=payload,
+                         name=name, job=job, phase=phase,
+                         batch_key=batch_key, payload=payload,
                          run_batch=run_batch))
 
     def record_meta(self, **decision) -> None:

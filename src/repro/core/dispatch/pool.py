@@ -372,9 +372,11 @@ class ServerPool:
 
     # -- dispatch ----------------------------------------------------------
     def submit(self, stream: str, fn: Callable[[], Any], *, priority: int = 0,
-               deadline: float | None = None, name: str = "") -> Request:
+               deadline: float | None = None, name: str = "",
+               job: int = 0, phase: str = "") -> Request:
         return self.server_for(stream).submit(
-            fn, priority=priority, deadline=deadline, name=name)
+            fn, priority=priority, deadline=deadline, name=name, job=job,
+            phase=phase)
 
     def submit_batch(self, stream: str, payload: Any, *,
                      run_batch: Callable[[list[Any]], list[Any]],

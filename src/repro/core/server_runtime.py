@@ -141,6 +141,9 @@ class Request:
     priority: int = 0  # larger = higher priority
     deadline: float | None = None  # absolute (time.monotonic) deadline, for EDF
     name: str = ""
+    # what spans record of it: the job it serves and its phase of that job
+    job: int = 0
+    phase: str = ""
     # filled by the server:
     result: Any = None
     error: BaseException | None = None
@@ -228,6 +231,8 @@ class AcceleratorServer:
         self.failed = False
         self.fail_cause: BaseException | None = None
         self._inflight: list[Request] | None = None
+        # core.spans.Recorder while the served path is traced, else None
+        self.recorder = None
         self._thread = threading.Thread(target=self._serve, name=name, daemon=True)
         self._thread.start()
 
@@ -255,9 +260,12 @@ class AcceleratorServer:
         priority: int = 0,
         deadline: float | None = None,
         name: str = "",
+        job: int = 0,
+        phase: str = "",
     ) -> Request:
         return self._enqueue(
-            Request(fn=fn, priority=priority, deadline=deadline, name=name))
+            Request(fn=fn, priority=priority, deadline=deadline, name=name,
+                    job=job, phase=phase))
 
     def call(self, fn: Callable[[], Any], *, priority: int = 0, name: str = "") -> Any:
         """Submit and suspend until completion (the common client pattern)."""
@@ -324,6 +332,14 @@ class AcceleratorServer:
         cb = self.on_failure
         if cb is not None:
             cb(self)
+
+    def set_recorder(self, rec) -> None:
+        """Trace this server into ``rec`` (a ``core.spans.Recorder``; None
+        stops).  An idle server wakes to open or close its ``server.idle``
+        span, so an idle period that spans the switch is traced from it."""
+        with self._lock:
+            self.recorder = rec
+            self._lock.notify_all()
 
     def __enter__(self) -> "AcceleratorServer":
         return self
@@ -400,22 +416,53 @@ class AcceleratorServer:
             result, error = None, e
         self._complete(req, result, error)
 
+    def _execute_traced(self, rec, batch: list[Request]) -> None:
+        """``_execute`` inside a ``server.call`` span (dequeue to clients
+        notified), then each request's ``server.queue`` span (submit to
+        dequeue).  ``ready`` is the counter of this server's jobs in their
+        decode phase, as the engine keeps it."""
+        head = batch[0]
+        call = rec.begin("server.call", job=head.job, phase=head.phase,
+                         rows=len(batch), ready=rec.count(self.name + ".ready"),
+                         jobs=tuple(r.job for r in batch))
+        try:
+            self._execute(batch)
+        finally:
+            rec.end(call)
+            for r in batch:
+                if r.start_t:
+                    rec.record("server.queue", r.submit_t, r.start_t,
+                               job=r.job, parent=r.job, phase=r.phase)
+
     def _serve(self) -> None:
         while True:
             with self._lock:
+                rec = idle = None
                 while not self._queue and not self._stop:
+                    if self.recorder is not rec:  # tracing turned on or off
+                        if idle is not None:
+                            rec.end(idle)
+                        rec = self.recorder
+                        idle = (rec.begin("server.idle") if rec is not None
+                                else None)
                     if self.beat is not None:
                         self.beat()
                         self._lock.wait(self.beat_interval_s)
                     else:
                         self._lock.wait()  # server suspends when idle
+                if idle is not None:
+                    rec.end(idle)
                 if not self._queue and self._stop:
                     return
                 batch = self._dequeue_locked()
                 self._inflight = batch
             if self.beat is not None:
                 self.beat()  # last beat before a (possibly stalling) call
-            self._execute(batch)
+            rec = self.recorder  # tracing may have been turned on meanwhile
+            if rec is None:
+                self._execute(batch)
+            else:
+                self._execute_traced(rec, batch)
             with self._lock:
                 self._inflight = None
                 if self.failed:

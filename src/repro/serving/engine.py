@@ -67,6 +67,7 @@ from repro.analysis.cost_model import autotune_buckets, bucket_up
 from repro.core.admission import PoolAdmissionController
 from repro.core.dispatch.pool import ServerPool
 from repro.core.faults import ServerFailedError, StreamShedError
+from repro.core.spans import Recorder
 from repro.core.task_model import GpuSegment, Task
 from repro.models import model as M
 from repro.runtime.straggler import DeadlineAwarePolicy, StepTimeWatchdog
@@ -143,6 +144,10 @@ class GenerationResult:
     # monotonic timestamp per recovery at which the retained prefix was
     # re-established on a survivor (resume point, for latency measurement)
     resumed_at_monotonic: list[float] = field(default_factory=list)
+    # monotonic time the first token was known (after the prefill's argmax)
+    first_token_at: float | None = None
+    # seconds blocked waiting for a decode slot, over every attempt
+    slot_wait_s: float = 0.0
 
 
 @dataclass
@@ -274,6 +279,8 @@ class ServeEngine:
             num_servers, cores_per_device=admission_cores,
             epsilon_ms=epsilon_ms, cost_model=cost_model)
         self.straggler = DeadlineAwarePolicy()
+        # core.spans.Recorder while the served path is traced, else None
+        self.recorder: Recorder | None = None
         # optional paged-KV accounting for the UNBATCHED path: generate()
         # holds block allocations for its sequence's lifetime; exhaustion
         # rejects the request before any device work is dispatched
@@ -371,6 +378,22 @@ class ServeEngine:
             self._export_kv = jax.jit(self._export_kv_impl)
             self._import_kv = jax.jit(self._import_kv_impl,
                                       donate_argnums=(0,))
+
+    def enable_tracing(self, recorder: Recorder | None = None) -> Recorder:
+        """Record spans and counters of the served path into ``recorder``
+        (a new one by default), shared by the engine and every server;
+        returns it.  A job already in its decode phase is not counted in
+        its server's ``ready`` counter."""
+        rec = recorder if recorder is not None else Recorder()
+        self.recorder = rec
+        for server in self.pool.servers:
+            server.set_recorder(rec)
+        return rec
+
+    def disable_tracing(self) -> None:
+        self.recorder = None
+        for server in self.pool.servers:
+            server.set_recorder(None)
 
     @staticmethod
     def _device_for(si: int):
@@ -772,10 +795,18 @@ class ServeEngine:
         state = self._paged[si]
         if state.pools is None:
             state.pools = self._make_pools(si)
+        rec = self.recorder
+        if rec is not None:
+            span = rec.begin("engine.stage")
         args = self._put(si, (np.int32(src_row), table, np.int32(slab),
                               np.int32(seg)))
+        if rec is not None:
+            rec.end(span)
+            span = rec.begin("engine.device")
         state.pools = jax.block_until_ready(
             self._insert_paged_jit(state.pools, cache, *args))
+        if rec is not None:
+            rec.end(span)
 
     def _run_paged_decode(self, si: int):
         """run_batch callable for server ``si`` (paged): payloads are
@@ -788,6 +819,9 @@ class ServeEngine:
         row (0 for slab-only families: no gather axis at all)."""
 
         def run(payloads):
+            rec = self.recorder
+            if rec is not None:
+                span = rec.begin("engine.stage")
             state = self._paged[si]
             bs = state.mgr.block_size
             n = len(payloads)
@@ -818,18 +852,29 @@ class ServeEngine:
                 pack[i, 4:] = table
             for i in range(n, n_pad):  # idempotent padding rows
                 pack[i] = pack[0]
+            # the recorded call time spans the put, the call and its wait
             t0 = time.monotonic()
+            params = self._params_on(si)
+            packed = self._put(si, pack[:n_pad, : 4 + w])
+            if rec is not None:
+                rec.end(span)
+                rec.tag(padded=n_pad, width=w)
+                span = rec.begin("engine.device")
             logits, state.pools = jax.block_until_ready(
-                self._decode_paged(self._params_on(si),
-                                   self._put(si, pack[:n_pad, : 4 + w]),
-                                   state.pools))
+                self._decode_paged(params, packed, state.pools))
             dt = time.monotonic() - t0
+            if rec is not None:
+                rec.end(span)
             if cold:  # now traced: later hits on this cell are warm
                 warm.add((n_pad, w))
             self.pool.servers[si].record_meta(
                 kind=self._decode_kind, rows=n, padded=n_pad, width=w,
                 compacted=n_pad < self.max_batch, seconds=dt, cold=cold)
+            if rec is not None:
+                span = rec.begin("engine.fetch")
             rows = np.asarray(logits)[:, -1]
+            if rec is not None:
+                rec.end(span)
             return [rows[i] for i in range(n)]
 
         return run
@@ -961,6 +1006,8 @@ class ServeEngine:
         prefill rewrites every in-range position while attention masks the
         rest."""
         src, dst = self._paged[src_si], self._paged[dst_si]
+        rec = self.recorder
+        job = rec.current_job() if rec is not None else 0
         with self._mig_lock:
             held = self._held.get(name)
             if held is None or (src_si, seq_id) not in held:
@@ -1006,7 +1053,8 @@ class ServeEngine:
                 return packed
 
             packed = self.pool.servers[src_si].submit(
-                gather, priority=prio, name=f"{name}/migrate-export").wait()
+                gather, priority=prio, name=f"{name}/migrate-export",
+                job=job, phase="migrate").wait()
 
             def scatter():
                 if dst.pools is None:
@@ -1023,7 +1071,8 @@ class ServeEngine:
                     seconds=time.monotonic() - t0, cold=cold)
 
             self.pool.servers[dst_si].submit(
-                scatter, priority=prio, name=f"{name}/migrate-import").wait()
+                scatter, priority=prio, name=f"{name}/migrate-import",
+                job=job, phase="migrate").wait()
         except BaseException:
             with self._mig_lock:
                 held = self._held.get(name)
@@ -1054,6 +1103,9 @@ class ServeEngine:
         cache, this payload's row index) — the caller inserts its row."""
 
         def run(payloads):
+            rec = self.recorder
+            if rec is not None:
+                span = rec.begin("engine.stage")
             n = len(payloads)
             n_pad = bucket_up(n, self._row_buckets)
             # safe fallback on the ROW axis (the bucket axis was already
@@ -1076,17 +1128,27 @@ class ServeEngine:
                 toks[i] = toks[0]
                 lens[i] = lens[0]
             batch = self._put(si, self._prefill_batch(toks, lens))
+            if rec is not None:
+                rec.end(span)
+                rec.tag(padded=n_pad, bucket=bucket)
+                span = rec.begin("engine.device")
             t0 = time.monotonic()
             logits, cache, _ = jax.block_until_ready(
                 self._prefill(self._params_on(si), batch))
             dt = time.monotonic() - t0
+            if rec is not None:
+                rec.end(span)
             if cold:
                 warm.add((n_pad, bucket))
             self.pool.servers[si].record_meta(
                 kind=self._prefill_kind, rows=n, padded=n_pad, bucket=bucket,
                 seconds=dt, cold=cold)
+            if rec is not None:
+                span = rec.begin("engine.fetch")
             rows = np.asarray(self._last_logits(logits, batch["lengths"]),
                               np.float32)
+            if rec is not None:
+                rec.end(span)
             return [(rows[i], cache, i) for i in range(n)]
 
         return run
@@ -1243,9 +1305,23 @@ class ServeEngine:
                  greedy: bool = True) -> GenerationResult:
         """Run one job of stream ``name``: prefill + ``steps`` decode
         segments, each arbitrated by the stream's server.  The calling
-        thread suspends between segments (never busy-waits)."""
-        if self.batching:
-            return self._generate_batched(name, prompt, steps=steps)
+        thread suspends between segments (never busy-waits).  While the
+        engine is traced the call is one ``job`` span."""
+        gen = (self._generate_batched if self.batching
+               else self._generate_unbatched)
+        rec = self.recorder
+        if rec is None:
+            return gen(name, prompt, steps=steps)
+        job = rec.begin_job(prompt_len=int(prompt.shape[1]), steps=steps)
+        try:
+            return gen(name, prompt, steps=steps)
+        finally:
+            rec.end(job)
+
+    def _generate_unbatched(self, name: str, prompt: np.ndarray, *,
+                            steps: int) -> GenerationResult:
+        rec = self.recorder
+        job = rec.current_job() if rec is not None else 0
         spec = self._streams[name]
         prio = self.straggler.boost(name, spec.priority)
         res = GenerationResult()
@@ -1259,13 +1335,18 @@ class ServeEngine:
             req = self.pool.submit(
                 name,
                 lambda: jax.block_until_ready(self._prefill(params, batch)),
-                priority=prio, name=f"{name}/prefill")
+                priority=prio, name=f"{name}/prefill", job=job,
+                phase="prefill")
             logits, cache, _ = req.wait()
             res.prefill_latency_s = time.monotonic() - t0
             self.straggler.observe(name, res.prefill_latency_s * 1e3)
 
             last = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             res.first_token = int(last[0])
+            res.first_token_at = time.monotonic()
+            if rec is not None:
+                rec.record("job.first_token", res.first_token_at,
+                           res.first_token_at)
             for i in range(steps):
                 step_batch = {"tokens": last[:, None]}
                 t1 = time.monotonic()
@@ -1273,7 +1354,8 @@ class ServeEngine:
                     name,
                     lambda sb=step_batch, c=cache: jax.block_until_ready(
                         self._decode(params, sb, c)),
-                    priority=prio, name=f"{name}/decode{i}")
+                    priority=prio, name=f"{name}/decode{i}", job=job,
+                    phase="decode")
                 logits, cache, _ = req.wait()
                 dt = time.monotonic() - t1
                 res.decode_latencies_s.append(dt)
@@ -1380,8 +1462,19 @@ class ServeEngine:
                 si, name, true_len, feeds, bucket)
         else:
             seq_id = self._kv_reserve(name, prefix[None, :], feeds)
+        rec = self.recorder
+        job = rec.current_job() if rec is not None else 0
+        # while traced: the open job.turnaround span, set once the job is
+        # in its decode phase (and counted in its server's ready counter)
+        turn = None
         try:
+            if rec is not None:
+                wait = rec.begin("job.slot_wait")
+            t_wait = time.monotonic()
             slot = self._acquire_slot(si)
+            res.slot_wait_s += time.monotonic() - t_wait
+            if rec is not None:
+                rec.end(wait)
             self._active_jobs[name] = si
             try:
                 t0 = time.monotonic()
@@ -1389,18 +1482,23 @@ class ServeEngine:
                     (prefix, true_len),
                     run_batch=self._run_prefill_batch(si, bucket),
                     batch_key=("prefill", si, bucket), priority=prio,
-                    name=f"{name}/prefill")
+                    name=f"{name}/prefill", job=job, phase="prefill")
                 row_logits, cache, src_row = req.wait()
                 if self.paged:
                     server.submit(
                         lambda: self._insert_slot_paged(
                             si, cache, src_row, table, slab, seg),
-                        priority=prio, name=f"{name}/insert").wait()
+                        priority=prio, name=f"{name}/insert", job=job,
+                        phase="insert").wait()
                 else:
                     server.submit(
                         lambda: self._insert_slot(
                             si, slot, cache, src_row),
-                        priority=prio, name=f"{name}/insert").wait()
+                        priority=prio, name=f"{name}/insert", job=job,
+                        phase="insert").wait()
+                if rec is not None:
+                    rec.add(server.name + ".ready")
+                    turn = rec.begin("job.turnaround")
                 res.prefill_latency_s = time.monotonic() - t0
                 self.straggler.observe(name, res.prefill_latency_s * 1e3)
 
@@ -1411,6 +1509,10 @@ class ServeEngine:
                     log.generated.append(token)
                 else:
                     log.first_token = res.first_token = token
+                    res.first_token_at = time.monotonic()
+                    if rec is not None:
+                        rec.record("job.first_token", res.first_token_at,
+                                   res.first_token_at)
                 length = true_len
                 run_batch = (self._run_paged_decode(si) if self.paged
                              else self._run_decode_batch(si))
@@ -1446,19 +1548,27 @@ class ServeEngine:
                                     raise
                                 else:
                                     self._release_slot(si, slot)
+                                    if turn is not None:
+                                        rec.add(server.name + ".ready", -1)
                                     slot, si = dst_slot, dst
                                     server = self.pool.servers[si]
+                                    if turn is not None:
+                                        rec.add(server.name + ".ready")
                                     run_batch = self._run_paged_decode(si)
                                     self._active_jobs[name] = si
                                     self.pool.complete_migration(name)
                     payload = ((token, table, length, slab, seg)
                                if self.paged else (slot, token))
+                    if turn is not None:
+                        rec.end(turn)
                     t1 = time.monotonic()
                     req = server.submit_batch(
                         payload, run_batch=run_batch,
                         batch_key=("decode", si), priority=prio,
-                        name=f"{name}/decode{i}")
+                        name=f"{name}/decode{i}", job=job, phase="decode")
                     row = req.wait()  # this row's logits, np.float32 (V,)
+                    if turn is not None:
+                        turn = rec.begin("job.turnaround")
                     dt = time.monotonic() - t1
                     res.decode_latencies_s.append(dt)
                     self.straggler.observe(name, dt * 1e3)
@@ -1468,6 +1578,9 @@ class ServeEngine:
                     log.generated.append(token)
                     i += 1
             finally:
+                if turn is not None:
+                    rec.end(turn)
+                    rec.add(server.name + ".ready", -1)
                 self._active_jobs.pop(name, None)
                 self._release_slot(si, slot)
         finally:
@@ -1801,6 +1914,7 @@ class ServeEngine:
                     num_slabs=self._num_slabs,
                     num_segments=self._num_segments))
             s = self.pool.servers[si]
+            s.set_recorder(self.recorder)
             if self._ft_params is not None:
                 s.max_retries = self._ft_params["max_retries"]
                 s.retry_backoff_s = self._ft_params["retry_backoff_s"]
