@@ -45,6 +45,9 @@ cache pools and its own compiled step programs):
     so same-bucket prompts from concurrent streams coalesce into one device
     call through the same BatchingServer discipline.  Per-row true lengths
     ride in the batch and become the cache's per-row ``pos``.
+  * Greedy pick on the device: the batched prefill and decode programs
+    return each row's next token id, so a step copies n int32 ids to the
+    host, never n rows of full-vocabulary logits.
   * Per-stream sequence state (generated tokens, the last token, lengths,
     block tables, latencies) lives in the calling thread, never in the
     batch: payloads carry only (token, table, length).
@@ -94,6 +97,12 @@ def _pow2_ladder(cap: int) -> tuple[int, ...]:
         v *= 2
     out.append(cap)
     return tuple(out)
+
+
+def _greedy(logits):
+    """Each row's greedy token at its last position: (n, T, V) logits ->
+    (n,) int32, the first maximum on ties, as ``np.argmax`` picks."""
+    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
 
 @dataclass
@@ -314,12 +323,13 @@ class ServeEngine:
                                  mode="prefill"))
         self._decode = jax.jit(
             lambda p, b, c: M.apply(cfg, p, b, mode="decode", cache=c))
-        # each prefill row's logits at its true last position — jitted so
-        # the pick compiles once per prefill cell (with it, in precompile)
-        # rather than as an eager gather per live-row count mid-traffic
-        self._last_logits = jax.jit(
-            lambda logits, lens: jnp.take_along_axis(
-                logits, (lens - 1)[:, None, None], axis=1)[:, 0])
+        # each prefill row's greedy token at its true last position, picked
+        # on the device so only n int32 ids reach the host — jitted so the
+        # pick compiles once per prefill cell (with it, in precompile)
+        # rather than eagerly per live-row count mid-traffic
+        self._last_token = jax.jit(
+            lambda logits, lens: _greedy(jnp.take_along_axis(
+                logits, (lens - 1)[:, None, None], axis=1)))
         self._streams: dict[str, StreamSpec] = {}
         # shape-bucket boundaries (tunable via tune_buckets()): batch rows
         # and prefill pad lengths default to the full pow2 ladder — exactly
@@ -369,7 +379,7 @@ class ServeEngine:
             # immediately replaced by the call's output
             self._insert_paged_jit = jax.jit(self._insert_paged_impl,
                                              donate_argnums=(0,))
-            self._decode_paged = jax.jit(self._decode_paged_impl,
+            self._decode_paged = jax.jit(self._decode_paged_impl_greedy,
                                          donate_argnums=(2,))
             # migration primitive: gather a stream's live blocks into one
             # packed buffer (source server), scatter them into fresh blocks
@@ -560,7 +570,8 @@ class ServeEngine:
         """Lower each cell's step programs from shapes alone (no device
         execution, no parameter placement).  Returns {CellKey: [Lowered,
         ...]}: ``("decode", rows, width)`` -> the paged decode step;
-        ``("prefill", rows, bucket)`` -> the bucketed prefill;
+        ``("prefill", rows, bucket)`` -> the bucketed prefill and its
+        last-position pick;
         ``("insert", rows, bucket)`` -> the scatter of such a prefill's
         cache into the pools; ``("migrate", width, block_size)`` -> the
         gather and the scatter of one migration.  ``sharding`` places every
@@ -600,11 +611,13 @@ class ServeEngine:
             elif base in ("prefill", "insert"):
                 batch = specs(self._prefill_batch(np.zeros((a, b), np.int32),
                                                   np.ones((a,), np.int32)))
+                logits, cache = specs(jax.eval_shape(self._prefill, params,
+                                                     batch)[:2])
                 if base == "prefill":
-                    out[cell] = [self._prefill.lower(params, batch)]
+                    out[cell] = [self._prefill.lower(params, batch),
+                                 self._last_token.lower(logits,
+                                                        batch["lengths"])]
                     continue
-                cache = specs(jax.eval_shape(self._prefill, params,
-                                             batch)[1])
                 table = spec(jax.ShapeDtypeStruct((self._paged[0].nb_max,),
                                                   jnp.int32))
                 out[cell] = [self._insert_paged_jit.lower(
@@ -654,9 +667,9 @@ class ServeEngine:
         return jax.tree.map(one, full, batched, self._batch_axes)
 
     def _decode_masked_impl(self, params, tokens, cache, active):
-        """One batched decode step over the slot cache; rows where ``active``
-        is False keep their previous cache (and their logits are garbage,
-        discarded by the caller)."""
+        """One batched decode step over the slot cache, returning each row's
+        greedy token id; rows where ``active`` is False keep their previous
+        cache (and their ids are garbage, discarded by the caller)."""
         logits, new_cache, _ = M.apply(self.cfg, params, {"tokens": tokens},
                                        mode="decode", cache=cache)
 
@@ -665,7 +678,8 @@ class ServeEngine:
             shape[ax] = n.shape[ax]
             return jnp.where(active.reshape(shape), n, o)
 
-        return logits, jax.tree.map(merge, cache, new_cache, self._batch_axes)
+        return _greedy(logits), jax.tree.map(merge, cache, new_cache,
+                                             self._batch_axes)
 
     def _acquire_slot(self, si: int) -> int:
         state = self._slots[si]
@@ -701,9 +715,10 @@ class ServeEngine:
 
     def _run_decode_batch(self, si: int):
         """run_batch callable for server ``si`` (masked-dense): payloads are
-        (slot, token) pairs; ONE masked device call serves them all.  The
-        staging arrays are the slot state's preallocated scratch — no
-        per-step host allocation."""
+        (slot, token) pairs; ONE masked device call serves them all, and
+        each result is that slot's next token id.  The staging arrays are
+        the slot state's preallocated scratch — no per-step host
+        allocation."""
 
         def run(payloads):
             state = self._slots[si]
@@ -714,11 +729,11 @@ class ServeEngine:
                 toks[slot, 0] = token
                 active[slot] = True
             toks_d, active_d = self._put(si, (toks, active))
-            logits, state.cache = jax.block_until_ready(
+            ids, state.cache = jax.block_until_ready(
                 self._decode_masked(self._params_on(si), toks_d,
                                     state.cache, active_d))
-            rows = np.asarray(logits[:, -1], np.float32)
-            return [rows[slot] for slot, _ in payloads]
+            ids = np.asarray(ids)
+            return [int(ids[slot]) for slot, _ in payloads]
 
         return run
 
@@ -788,6 +803,13 @@ class ServeEngine:
                                        mode="decode", cache=cache)
         return logits, {k: new_cache[k] for k in self._pool_kinds}
 
+    def _decode_paged_impl_greedy(self, params, packed, pools):
+        """``_decode_paged_impl`` with the greedy pick on the device: each
+        row's next token id, (n,) int32, in place of its (n, 1, V) logits,
+        so a step sends n ids to the host rather than n vocabulary rows."""
+        logits, new_pools = self._decode_paged_impl(params, packed, pools)
+        return _greedy(logits), new_pools
+
     def _insert_slot_paged(self, si: int, cache, src_row: int,
                            table: np.ndarray, slab: int = 0,
                            seg: int = 0) -> None:
@@ -810,7 +832,8 @@ class ServeEngine:
 
     def _run_paged_decode(self, si: int):
         """run_batch callable for server ``si`` (paged): payloads are
-        (token, block_table, length, slab, segment) tuples.  Slot compaction
+        (token, block_table, length, slab, segment) tuples, and each result
+        is that row's next token id, picked on the device.  Slot compaction
         + length bucketing happen here: only the live rows enter the device
         call (padded to the next power of two by duplicating row 0 —
         duplicate scatter lanes write identical values and slabs are
@@ -860,8 +883,12 @@ class ServeEngine:
                 rec.end(span)
                 rec.tag(padded=n_pad, width=w)
                 span = rec.begin("engine.device")
-            logits, state.pools = jax.block_until_ready(
-                self._decode_paged(params, packed, state.pools))
+            ids, state.pools = self._decode_paged(params, packed,
+                                                  state.pools)
+            # queue the ids' copy behind the step, so that they reach the
+            # host as the step ends rather than on a fetch issued after it
+            ids.copy_to_host_async()
+            jax.block_until_ready((ids, state.pools))
             dt = time.monotonic() - t0
             if rec is not None:
                 rec.end(span)
@@ -872,10 +899,10 @@ class ServeEngine:
                 compacted=n_pad < self.max_batch, seconds=dt, cold=cold)
             if rec is not None:
                 span = rec.begin("engine.fetch")
-            rows = np.asarray(logits)[:, -1]
+            ids = np.asarray(ids)
             if rec is not None:
                 rec.end(span)
-            return [rows[i] for i in range(n)]
+            return [int(ids[i]) for i in range(n)]
 
         return run
 
@@ -1099,8 +1126,9 @@ class ServeEngine:
     def _run_prefill_batch(self, si: int, bucket: int):
         """run_batch callable coalescing same-bucket prefills: payloads are
         (prompt_row, true_len); ONE device call prefills them all, padded to
-        ``bucket``.  Each result is (last-token logits row, the coalesced
-        cache, this payload's row index) — the caller inserts its row."""
+        ``bucket``.  Each result is (the greedy token id at the row's last
+        position, picked on the device; the coalesced cache; this payload's
+        row index) — the caller inserts its row."""
 
         def run(payloads):
             rec = self.recorder
@@ -1145,11 +1173,10 @@ class ServeEngine:
                 seconds=dt, cold=cold)
             if rec is not None:
                 span = rec.begin("engine.fetch")
-            rows = np.asarray(self._last_logits(logits, batch["lengths"]),
-                              np.float32)
+            ids = np.asarray(self._last_token(logits, batch["lengths"]))
             if rec is not None:
                 rec.end(span)
-            return [(rows[i], cache, i) for i in range(n)]
+            return [(int(ids[i]), cache, i) for i in range(n)]
 
         return run
 
@@ -1289,7 +1316,7 @@ class ServeEngine:
                 np.zeros((rows, bucket), np.int32), np.ones((rows,))))
             logits, cache, _ = self._prefill(self._params_on(si), batch)
             jax.block_until_ready(
-                self._last_logits(logits, batch["lengths"]))
+                self._last_token(logits, batch["lengths"]))
             if self.paged:
                 state = self._paged[si]
                 table = np.full((state.nb_max,), state.scratch_block,
@@ -1483,7 +1510,7 @@ class ServeEngine:
                     run_batch=self._run_prefill_batch(si, bucket),
                     batch_key=("prefill", si, bucket), priority=prio,
                     name=f"{name}/prefill", job=job, phase="prefill")
-                row_logits, cache, src_row = req.wait()
+                token, cache, src_row = req.wait()
                 if self.paged:
                     server.submit(
                         lambda: self._insert_slot_paged(
@@ -1502,7 +1529,6 @@ class ServeEngine:
                 res.prefill_latency_s = time.monotonic() - t0
                 self.straggler.observe(name, res.prefill_latency_s * 1e3)
 
-                token = int(np.argmax(row_logits))
                 if append_first:  # recovery attempt: resume point reached
                     res.resumed_at_monotonic.append(time.monotonic())
                     res.tokens.append(token)
@@ -1566,13 +1592,12 @@ class ServeEngine:
                         payload, run_batch=run_batch,
                         batch_key=("decode", si), priority=prio,
                         name=f"{name}/decode{i}", job=job, phase="decode")
-                    row = req.wait()  # this row's logits, np.float32 (V,)
+                    token = req.wait()  # this row's next token id
                     if turn is not None:
                         turn = rec.begin("job.turnaround")
                     dt = time.monotonic() - t1
                     res.decode_latencies_s.append(dt)
                     self.straggler.observe(name, dt * 1e3)
-                    token = int(np.argmax(row))
                     length += 1
                     res.tokens.append(token)
                     log.generated.append(token)

@@ -310,6 +310,32 @@ class TestPagedPoolServing:
         finally:
             eng.close()
 
+    @pytest.mark.parametrize("program", ["_decode_paged", "_last_token"])
+    def test_traffic_precompile_leaves_serving_no_trace(self, setup,
+                                                        program):
+        """After precompile(traffic=...), as the launcher and the benchmark
+        warm an engine, serving a job's prefill and decode steps adds no
+        trace to the paged decode step (its on-device pick included) nor
+        to the prefill's last-position pick."""
+        cfg, params = setup
+        eng = ServeEngine(cfg, params, max_seq=32, num_servers=1,
+                          batching=True, max_batch=4, paged=True,
+                          kv_block_size=8)
+        try:
+            prompt = np.array([[1, 2, 3]], np.int32)
+            eng.tune_buckets([3], steps_hint=4)
+            cells = eng.traffic_cells([(3, 4)], concurrency=1)
+            eng.precompile((3,), traffic=cells)
+            jitted = getattr(eng, program)
+            before = jitted._cache_size()
+            assert before > 0
+            assert eng.admit(_spec("t", 1)).admitted
+            res = eng.generate("t", prompt, steps=4)
+            assert len(res.tokens) == 4
+            assert jitted._cache_size() == before
+        finally:
+            eng.close()
+
     def test_tune_buckets_minimizes_padding_waste(self, setup):
         """Bucket auto-tuning: with max_buckets=2 and short prompts the
         prefill ladder collapses to {tight cover, max_seq} and decode
