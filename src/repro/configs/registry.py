@@ -38,6 +38,14 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
     v_head_dim: int = 0
+    # YaRN rope scaling of MLA's rotary part (DeepSeek-V2 ``rope_scaling``),
+    # which every MLA config sets; no other attention reads these
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # MoE
     num_experts: int = 0
@@ -46,6 +54,9 @@ class ModelConfig:
     moe_d_ff: int = 0
     first_dense_layers: int = 0
     capacity_factor: float = 1.25
+    # top-k routing weights: renormalised to sum to 1 (Qwen3-MoE), or the
+    # raw softmax probabilities (DeepSeek-V2)
+    norm_topk_prob: bool = True
 
     # SSM (mamba2)
     ssm_state_dim: int = 0

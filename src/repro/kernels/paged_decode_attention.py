@@ -139,8 +139,7 @@ def _paged_mla_kernel(tables_ref, len_ref, q_ref, ckv_ref, krope_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_mla_decode_attention(q_lat, q_rope, ckv_pool, krope_pool,
-                               block_tables, lengths, *,
-                               scale: float | None = None,
+                               block_tables, lengths, *, scale: float,
                                interpret: bool = False):
     """Absorbed-MLA paged decode: q_lat (B,Nq,R) latent-projected queries,
     q_rope (B,Nq,PR); pools ckv (NB,BS,R), k_rope (NB,BS,PR);
@@ -149,7 +148,8 @@ def paged_mla_decode_attention(q_lat, q_rope, ckv_pool, krope_pool,
     Per position the key is concat(c_kv, k_rope) and the VALUE is c_kv
     itself, so the kernel is the GQA paged sweep with a different tile
     addressing — the caller applies w_uv to the returned latent output.
-    ``scale`` should be 1/sqrt(qk_nope + qk_rope); NOTE the pools carry no
+    ``scale`` is the model's softmax scale, ``layers.mla_softmax_scale(cfg)``
+    (1/sqrt(qk_nope + qk_rope) times YaRN's mscale^2); NOTE the pools carry no
     head axis (the latent is shared by every head — MLA's memory win), so
     each of the Nq sweeps re-reads the same blocks.
     """
@@ -157,7 +157,6 @@ def paged_mla_decode_attention(q_lat, q_rope, ckv_pool, krope_pool,
     pr = q_rope.shape[-1]
     bs = ckv_pool.shape[1]
     w = block_tables.shape[1]
-    scale = scale if scale is not None else (r + pr) ** -0.5
     q = jnp.concatenate([q_lat, q_rope], axis=-1)[:, :, None, :]
 
     kernel = functools.partial(_paged_mla_kernel, scale=scale, bs=bs, r=r)

@@ -69,7 +69,7 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
 
 def paged_mla_decode_attention_ref(q_lat, q_rope, ckv_pool, krope_pool,
                                    block_tables, lengths, *,
-                                   scale: float | None = None):
+                                   scale: float):
     """q_lat (B,Nq,R); q_rope (B,Nq,PR); pools ckv (NB,BS,R) /
     k_rope (NB,BS,PR); block_tables (B,W); lengths (B,) -> o_lat (B,Nq,R).
 
@@ -79,7 +79,6 @@ def paged_mla_decode_attention_ref(q_lat, q_rope, ckv_pool, krope_pool,
     bs = ckv_pool.shape[1]
     b, w = block_tables.shape
     r, pr = q_lat.shape[-1], q_rope.shape[-1]
-    scale = scale if scale is not None else (r + pr) ** -0.5
     ckv = ckv_pool[block_tables].reshape(b, w * bs, r).astype(f32)
     krope = krope_pool[block_tables].reshape(b, w * bs, pr).astype(f32)
     logits = (jnp.einsum("bnr,btr->bnt", q_lat.astype(f32), ckv)

@@ -91,6 +91,55 @@ def apply_rope(x, cos, sin):
     return out.astype(dt)
 
 
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``scale``-fold."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(cfg, dim: int) -> tuple[int, int]:
+    """The rotary pair indices between which YaRN ramps from the original
+    frequencies (below ``low``) to the interpolated ones (above ``high``):
+    where a pair turns ``beta_fast`` and ``beta_slow`` times over the
+    original context."""
+
+    def dim_at(rotations):
+        return (dim * math.log(cfg.rope_original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    return (max(math.floor(dim_at(cfg.rope_beta_fast)), 0),
+            min(math.ceil(dim_at(cfg.rope_beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(cfg, dim: int):
+    """YaRN rotary frequencies (dim/2,) in fp32, as DeepSeek-V2 computes
+    them: the original ones below the correction range, those divided by
+    the scaling factor above it, and a linear ramp between."""
+    exps = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / cfg.rope_theta ** exps
+    inter = 1.0 / (cfg.rope_scaling_factor * cfg.rope_theta ** exps)
+    low, high = yarn_correction_range(cfg, dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def mla_rope_cos_sin(cfg, positions, dim: int):
+    """MLA's rotary cos/sin (..., S, dim/2) under the config's YaRN scaling:
+    ``yarn_inv_freq``, and cos and sin times mscale / mscale_all_dim."""
+    ang = positions[..., None].astype(jnp.float32) * yarn_inv_freq(cfg, dim)
+    m = (yarn_get_mscale(cfg.rope_scaling_factor, cfg.rope_mscale)
+         / yarn_get_mscale(cfg.rope_scaling_factor, cfg.rope_mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def deinterleave(x):
+    """(..., H) -> the even features, then the odd ones.  DeepSeek-V2
+    rotates adjacent feature pairs: it de-interleaves before a rotate-half
+    rope (and the rotated vector stays in that order)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def sinusoidal_positions(positions, d_model: int):
     """Whisper-style absolute sinusoidal embeddings, (..., S) -> (..., S, D)."""
     half = d_model // 2
@@ -239,14 +288,25 @@ def init_mla(cfg, key, dtype) -> Params:
         "w_uk": dense_init(ks[2], (r, n, pn), dtype),
         "w_uv": dense_init(ks[3], (r, n, hv), dtype),
         "wo": dense_init(ks[4], (n, hv, d), dtype, scale=1.0 / math.sqrt(n * hv)),
+        "kv_norm": init_rms_norm(r, dtype),  # on the latent, before caching
     }
 
 
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim) times YaRN's mscale(all_dim)^2 (DeepSeek-V2)."""
+    m = yarn_get_mscale(cfg.rope_scaling_factor, cfg.rope_mscale_all_dim)
+    return m * m / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+@jax.named_scope("mla")
 def mla_attention(cfg, p: Params, x, *, positions, cache=None, layer_cache=None):
     """MLA: KV compressed to a ``kv_lora_rank`` latent + one shared rotary
-    key.  The cache stores only (c_kv, k_rope) — the paper-accurate memory
-    win.  Decode uses the absorbed formulation (queries projected into the
-    latent space; no per-step K/V decompression).
+    key.  The latent is RMS-normalised (``kv_norm``) before it is cached or
+    expanded, so the cache stores only the normalised (c_kv, k_rope) — the
+    paper-accurate memory win.  Decode uses the absorbed formulation
+    (queries projected into the latent space; no per-step K/V
+    decompression).  The rotary parts follow DeepSeek-V2: adjacent pairs
+    (``deinterleave``), YaRN frequencies and softmax scale.
 
     Paged decode: ``layer_cache = (ckv_stack, krope_stack, lidx, tables,
     pos)`` — latent block pools (L, NB, BS, r) / (L, NB, BS, pr) shared
@@ -256,16 +316,18 @@ def mla_attention(cfg, p: Params, x, *, positions, cache=None, layer_cache=None)
     b, s, d = x.shape
     n = cfg.num_heads
     r, pr, pn, hv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(pn + pr)
+    scale = mla_softmax_scale(cfg)
 
     q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"])  # (B,S,N,pn+pr)
     q_nope, q_rope = q[..., :pn], q[..., pn:]
     ckv_full = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])  # (B,S,r+pr)
     c_kv, k_rope = ckv_full[..., :r], ckv_full[..., r:]
+    c_kv = rms_norm(c_kv, p["kv_norm"]["scale"], cfg.norm_eps)
 
-    cos, sin = rope_cos_sin(positions, pr, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos, sin)
-    k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]  # shared head
+    cos, sin = mla_rope_cos_sin(cfg, positions, pr)
+    q_rope = apply_rope(deinterleave(q_rope), cos, sin)
+    k_rope = apply_rope(deinterleave(k_rope)[:, :, None, :], cos,
+                        sin)[:, :, 0, :]  # shared head
 
     if layer_cache is None:
         k_nope = jnp.einsum("bsr,rnh->bsnh", c_kv, p["w_uk"])

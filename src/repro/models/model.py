@@ -124,8 +124,8 @@ def _attn_params(cfg) -> int:
     if cfg.attn_type == "mla":
         r, pr, pn, hv, n = (cfg.kv_lora_rank, cfg.qk_rope_head_dim,
                             cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.num_heads)
-        return (d * n * (pn + pr) + d * (r + pr) + r * n * pn + r * n * hv
-                + n * hv * d)
+        return (d * n * (pn + pr) + d * (r + pr) + r + r * n * pn
+                + r * n * hv + n * hv * d)  # r: the latent's norm
     nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     return d * hd * (nq + 2 * nkv) + nq * hd * d
 

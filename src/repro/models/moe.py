@@ -15,9 +15,14 @@ Three execution paths, all numerically equivalent (up to capacity drops):
                  computes only its local experts and partial outputs are
                  psum-combined.  Used for decode (seq length 1).
 
-Routing: top-k over softmax(router logits), renormalized over the selected
-experts (DeepSeek/Qwen convention), plus the standard load-balance auxiliary
-loss.
+Routing: top-k over softmax(router logits) in float32.  With
+``norm_topk_prob`` (Qwen3-MoE) the top-k weights are renormalised to sum to
+1; without it (DeepSeek-V2) they are the raw probabilities.  Plus the
+standard load-balance auxiliary loss.
+
+Named scopes ``moe.router``, ``moe.experts`` and ``moe.shared`` label the
+device ops of each part in traces (on the distributed paths the router
+runs inside ``moe.experts``).
 """
 
 from __future__ import annotations
@@ -54,13 +59,17 @@ def init_moe(cfg, key, dtype) -> Params:
     return p
 
 
+@jax.named_scope("moe.router")
 def router_topk(cfg, p: Params, x):
     """x (T, D) -> (idx (T,K), weights (T,K), aux_loss scalar)."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p["router"])
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     k = cfg.num_experts_per_tok
     top_p, idx = jax.lax.top_k(probs, k)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    weights = top_p
+    if cfg.norm_topk_prob:
+        weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
     e = cfg.num_experts
     occupancy = jnp.zeros((e,), jnp.float32).at[idx.reshape(-1)].add(1.0)
@@ -114,17 +123,20 @@ def moe_dense(cfg, p: Params, x):
     b, s, d = x.shape
     tokens = x.reshape(-1, d)
     idx, weights, aux = router_topk(cfg, p, tokens)
-    # (E, T, D): every expert everywhere
-    g = jax.nn.silu(jnp.einsum("td,edf->etf", tokens, p["experts"]["w_gate"]))
-    u = jnp.einsum("td,edf->etf", tokens, p["experts"]["w_up"])
-    y_all = jnp.einsum("etf,efd->etd", g * u, p["experts"]["w_down"])
-    combine = jnp.zeros((tokens.shape[0], cfg.num_experts), x.dtype)
-    tk = jnp.arange(tokens.shape[0])[:, None]
-    combine = combine.at[tk, idx].add(weights)
-    out = jnp.einsum("te,etd->td", combine, y_all)
+    with jax.named_scope("moe.experts"):
+        # (E, T, D): every expert everywhere
+        g = jax.nn.silu(jnp.einsum("td,edf->etf", tokens,
+                                   p["experts"]["w_gate"]))
+        u = jnp.einsum("td,edf->etf", tokens, p["experts"]["w_up"])
+        y_all = jnp.einsum("etf,efd->etd", g * u, p["experts"]["w_down"])
+        combine = jnp.zeros((tokens.shape[0], cfg.num_experts), x.dtype)
+        tk = jnp.arange(tokens.shape[0])[:, None]
+        combine = combine.at[tk, idx].add(weights)
+        out = jnp.einsum("te,etd->td", combine, y_all)
     return out.reshape(b, s, d), aux
 
 
+@jax.named_scope("moe.experts")
 def _moe_local(cfg, router, experts, tokens, *, capacity: int, e_local: int,
                axis: str | None):
     """Per-shard MoE body (runs inside shard_map, or standalone if axis None
@@ -151,6 +163,7 @@ def _moe_local(cfg, router, experts, tokens, *, capacity: int, e_local: int,
     return out, aux
 
 
+@jax.named_scope("moe.experts")
 def _moe_replicated_body(cfg, router, experts, tokens, *, capacity: int, axis: str):
     """Decode path: tokens replicated over the model axis; each shard runs
     its local experts only and partial results are psum-combined."""
@@ -171,6 +184,7 @@ def _moe_replicated_body(cfg, router, experts, tokens, *, capacity: int, axis: s
     return jax.lax.psum(out, axis), aux
 
 
+@jax.named_scope("moe.experts")
 def _moe_decode_tpdata(cfg, rules, p: Params, x):
     """§Perf decode path: expert FFN width sharded over the DP axes.
 
@@ -293,7 +307,8 @@ def moe_layer(cfg, p: Params, x):
             out, aux = moe_dense(cfg, p, x)
 
     if "shared" in p:
-        out = out + mlp(cfg, p["shared"], x)
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(cfg, p["shared"], x)
     return shd.shard_hidden(out), aux
 
 

@@ -153,7 +153,8 @@ def test_cache_family_resolves(arch):
 
 
 @pytest.mark.parametrize("arch", ["llama3_405b", "qwen3_moe_235b_a22b",
-                                  "mamba2_780m", "zamba2_7b", "whisper_medium"])
+                                  "deepseek_v2_lite_16b", "mamba2_780m",
+                                  "zamba2_7b", "whisper_medium"])
 def test_param_count_matches_init(arch):
     """Analytical param_count (used for roofline MODEL_FLOPS) must match the
     actual initialized tree of the reduced config."""

@@ -199,10 +199,11 @@ class TestPagedMLADecodeAttention:
                                                 dtype)
         lengths = jax.random.randint(jax.random.fold_in(key, 1), (b,), 1,
                                      w * bs + 1)
+        scale = (r + pr) ** -0.5
         got = paged_mla_decode_attention(ql, qr, ckv, krope, tables, lengths,
-                                         interpret=True)
+                                         scale=scale, interpret=True)
         want = ref.paged_mla_decode_attention_ref(ql, qr, ckv, krope, tables,
-                                                  lengths)
+                                                  lengths, scale=scale)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **TOL[dtype])
 
@@ -211,15 +212,16 @@ class TestPagedMLADecodeAttention:
         ql, qr, ckv, krope, tables = self._make(jax.random.PRNGKey(22), b, nq,
                                                 r, pr, nb, bs, w, jnp.float32)
         lengths = jnp.array([1, 16, 17], jnp.int32)
+        scale = (r + pr) ** -0.5
         got = paged_mla_decode_attention(ql, qr, ckv, krope, tables, lengths,
-                                         interpret=True)
+                                         scale=scale, interpret=True)
         want = ref.paged_mla_decode_attention_ref(ql, qr, ckv, krope, tables,
-                                                  lengths)
+                                                  lengths, scale=scale)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
         tables2 = tables.at[0, 1:].set(0).at[1, 1:].set(0)
         got2 = paged_mla_decode_attention(ql, qr, ckv, krope, tables2, lengths,
-                                          interpret=True)
+                                          scale=scale, interpret=True)
         np.testing.assert_array_equal(np.asarray(got[:2]), np.asarray(got2[:2]))
 
     def test_custom_scale(self):
