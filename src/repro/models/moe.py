@@ -1,10 +1,16 @@
 """Mixture-of-Experts layer.
 
-Three execution paths, all numerically equivalent (up to capacity drops):
+Four execution paths, all numerically equivalent (up to capacity drops):
 
   * dense      — every expert runs on every token, combined by routing
-                 weights.  Exact (dropless); used for CPU smoke tests and as
-                 the reference oracle for the distributed paths.
+                 weights.  Exact (dropless); the one-chip path when a call's
+                 selections can cover every expert (prefill, training), and
+                 the reference oracle for the other paths.
+  * routed     — the dense path's arithmetic restricted to the experts the
+                 router selected: each is read once, by index, from the
+                 stacked weights.  Exact (dropless); the one-chip path when a
+                 call selects fewer experts than there are (decode; see
+                 ``routes_sparsely``).
   * ep_a2a     — expert parallelism over the 'model' mesh axis via
                  shard_map: tokens are dispatched into per-expert capacity
                  buffers locally, exchanged with a single all_to_all,
@@ -136,6 +142,58 @@ def moe_dense(cfg, p: Params, x):
     return out.reshape(b, s, d), aux
 
 
+def routes_sparsely(cfg, n_tokens: int) -> bool:
+    """Whether a call of ``n_tokens`` tokens selects fewer experts than
+    there are, so that the routed path reads fewer than the dense one."""
+    return n_tokens * cfg.num_experts_per_tok < cfg.num_experts
+
+
+def moe_routed(cfg, p: Params, x, layer=None):
+    """Routed-experts path (exact, dropless): each distinct expert the
+    router selected runs its SwiGLU on every token of the call, added under
+    its column of the combine matrix (zero for rows that did not route
+    there).  Its weights are sliced by index straight from the stacked
+    arrays (``layer`` as in ``moe_layer``), so only the selected slabs are
+    read, each once."""
+    b, s, d = x.shape
+    tokens = x.reshape(-1, d)
+    t = tokens.shape[0]
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    idx, weights, aux = router_topk(cfg, p, tokens)
+    experts = p["experts"]
+    with jax.named_scope("moe.experts"):
+        combine = jnp.zeros((t, e), x.dtype)
+        combine = combine.at[jnp.arange(t)[:, None], idx].add(weights)
+        picked = jnp.zeros((e,), bool).at[idx.reshape(-1)].set(True)
+        slots = min(e, t * k)
+        (ids,) = jnp.nonzero(picked, size=slots, fill_value=0)
+        count = jnp.sum(picked)
+
+        def slab(name, eid):
+            w = experts[name]
+            if layer is None:
+                return jax.lax.dynamic_index_in_dim(w, eid, keepdims=False)
+            return jax.lax.dynamic_slice(
+                w, (layer, eid, 0, 0), (1, 1, *w.shape[2:]))[0, 0]
+
+        def add_expert(eid, out):
+            g = jax.nn.silu(jnp.einsum("td,df->tf", tokens,
+                                       slab("w_gate", eid)))
+            u = jnp.einsum("td,df->tf", tokens, slab("w_up", eid))
+            y = jnp.einsum("tf,fd->td", g * u, slab("w_down", eid))
+            c = jax.lax.dynamic_index_in_dim(combine, eid, axis=1)
+            return out + c.astype(jnp.float32) * y.astype(jnp.float32)
+
+        out = jnp.zeros((t, d), jnp.float32)
+        for j in range(slots):
+            if j < k:  # row 0 alone selects k distinct experts
+                out = add_expert(ids[j], out)
+            else:  # a slot past the distinct count reads nothing
+                out = jax.lax.cond(j < count, partial(add_expert, ids[j]),
+                                   lambda o: o, out)
+    return out.astype(x.dtype).reshape(b, s, d), aux
+
+
 @jax.named_scope("moe.experts")
 def _moe_local(cfg, router, experts, tokens, *, capacity: int, e_local: int,
                axis: str | None):
@@ -246,15 +304,29 @@ def _moe_decode_tpdata(cfg, rules, p: Params, x):
       p["experts"]["w_down"], x)
 
 
-def moe_layer(cfg, p: Params, x):
+def moe_layer(cfg, p: Params, x, layer=None):
     """Dispatching MoE layer: picks the execution path from the active
-    sharding rules.  Returns (out (B,S,D), aux_loss)."""
+    sharding rules and, with no mesh, from the call's shape
+    (``routes_sparsely``).  Returns (out (B,S,D), aux_loss).
+
+    With ``layer`` given, ``p["experts"]`` holds the experts of every
+    layer stacked on a leading axis, and ``layer`` indexes this one.  Layer
+    scans pass them so: a branch of the routed path then slices just its
+    expert's slabs from the stack, where a layer's slice handed to it would
+    be copied whole by the compiler."""
     b, s, d = x.shape
     rules = shd.current_rules()
     k = cfg.num_experts_per_tok
     e = cfg.num_experts
 
-    if rules is None or rules.mesh is None or rules.mesh.shape[rules.model_axis] == 1:
+    one_chip = (rules is None or rules.mesh is None
+                or rules.mesh.shape[rules.model_axis] == 1)
+    routed = one_chip and routes_sparsely(cfg, b * s)
+    if layer is not None and not routed:
+        p = {**p, "experts": jax.tree.map(lambda w: w[layer], p["experts"])}
+    if routed:
+        out, aux = moe_routed(cfg, p, x, layer)
+    elif one_chip:
         out, aux = moe_dense(cfg, p, x)
     else:
         mesh = rules.mesh

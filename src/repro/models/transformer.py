@@ -88,11 +88,13 @@ def init_params(cfg, key):
 
 
 def block(cfg, p, x, *, positions, mrope_positions=None, mode: str,
-          layer_cache=None, use_moe: bool, lengths=None):
+          layer_cache=None, use_moe: bool, lengths=None, layer=None):
     """One transformer block.  Returns (x, new_layer_cache, aux_loss).
     ``lengths`` (B,) are the true per-row prompt lengths of a padded
     (bucketed) prefill — only the SSM prefill needs them (its recurrent
-    state is polluted by pad positions unless dt is masked)."""
+    state is polluted by pad positions unless dt is masked).  ``layer``:
+    the block's index into ``p["moe"]["experts"]`` where those hold every
+    layer's experts (``moe.moe_layer``)."""
     kind = _mixer_kind(cfg)
     aux = jnp.zeros((), jnp.float32)
     h = L.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
@@ -116,10 +118,28 @@ def block(cfg, p, x, *, positions, mrope_positions=None, mode: str,
     x = x + out
     h = L.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
     if use_moe:
-        out, aux = M.moe_layer(cfg, p["moe"], h)
+        out, aux = M.moe_layer(cfg, p["moe"], h, layer)
     else:
         out = L.mlp(cfg, p["mlp"], h)
     return x + out, new_cache, aux
+
+
+def _split_experts(cfg, layers):
+    """(the layer stack without its routed experts, the experts stacked
+    whole, or None): each scanned block then slices its own from the whole
+    stack (``moe.moe_layer``'s ``layer``)."""
+    if not cfg.is_moe:
+        return layers, None
+    moe = dict(layers["moe"])
+    experts = moe.pop("experts")
+    return {**layers, "moe": moe}, experts
+
+
+def _with_experts(lp, experts):
+    """A scanned block's params with the whole expert stack put back."""
+    if experts is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], "experts": experts}}
 
 
 # --------------------------------------------------------------------------
@@ -328,6 +348,12 @@ def forward(cfg, params, batch, *, mode: str, cache=None, remat: bool = False,
             first_caches.append(c)
 
     # -- scanned stack ---------------------------------------------------
+    # Serving passes the routed experts whole, for the routed path to read
+    # just the selected slabs; training keeps them sliced by the scan, where
+    # the gradient of a stack the scan closes over is summed whole a layer.
+    layers, experts = params["layers"], None
+    if mode != "train":
+        layers, experts = _split_experts(cfg, layers)
     if paged:
         # the pool stacks ride the scan as CARRY (not xs/ys): each layer
         # scatters one row (or slab) and gathers its live window in place,
@@ -342,38 +368,38 @@ def forward(cfg, params, batch, *, mode: str, cache=None, remat: bool = False,
             else:  # gqa / mla block pools share the table indirection
                 lc = (p0, p1, lidx, cache["block_tables"], cache["pos"])
             x, (p0, p1), aux = block(
-                cfg, lp, x, positions=positions,
+                cfg, _with_experts(lp, experts), x, positions=positions,
                 mrope_positions=mrope_positions, mode=mode, layer_cache=lc,
-                use_moe=cfg.is_moe)
+                use_moe=cfg.is_moe, layer=lidx)
             return (x, aux_acc + aux, p0, p1, lidx + 1), None
 
         p0, p1 = cache["layers"]
         carry = (x, aux_total, p0, p1, jnp.int32(0))
-        (x, aux_total, p0, p1, _), _ = jax.lax.scan(
-            paged_body, carry, params["layers"])
+        (x, aux_total, p0, p1, _), _ = jax.lax.scan(paged_body, carry, layers)
         layer_caches = (p0, p1)
     else:
         def body(carry, inp):
-            x, aux_acc = carry
+            x, aux_acc, lidx = carry
             if mode == "decode":
                 lp, lc = inp
                 lc = lc + (cache["pos"],)
             else:
                 lp, lc = inp, None
-            x, c, aux = block(cfg, lp, x, positions=positions,
+            x, c, aux = block(cfg, _with_experts(lp, experts), x,
+                              positions=positions,
                               mrope_positions=mrope_positions, mode=mode,
                               layer_cache=lc, use_moe=cfg.is_moe,
-                              lengths=prefill_lengths)
-            return (x, aux_acc + aux), c
+                              lengths=prefill_lengths,
+                              layer=None if experts is None else lidx)
+            return (x, aux_acc + aux, lidx + 1), c
 
         body_fn = body
         if remat:
             body_fn = jax.checkpoint(body, policy=remat_policy)
 
-        xs = (params["layers"], cache["layers"]) if mode == "decode" \
-            else params["layers"]
-        (x, aux_total), layer_caches = jax.lax.scan(body_fn, (x, aux_total),
-                                                    xs)
+        xs = (layers, cache["layers"]) if mode == "decode" else layers
+        (x, aux_total, _), layer_caches = jax.lax.scan(
+            body_fn, (x, aux_total, jnp.int32(0)), xs)
 
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = unembed(cfg, params, x)

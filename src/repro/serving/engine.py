@@ -73,6 +73,7 @@ from repro.core.faults import ServerFailedError, StreamShedError
 from repro.core.spans import Recorder
 from repro.core.task_model import GpuSegment, Task
 from repro.models import model as M
+from repro.models.moe import routes_sparsely
 from repro.runtime.straggler import DeadlineAwarePolicy, StepTimeWatchdog
 from repro.serving.kvcache import (FAMILIES, OutOfBlocksError,
                                    PagedKVCacheManager)
@@ -433,6 +434,14 @@ class ServeEngine:
         dev = self._devices[si]
         with jax.default_device(dev):
             return jax.device_put(make(), dev)
+
+    def _moe_tag(self, n_tokens: int) -> dict:
+        """Tag of an ``engine.device`` span: whether a call of ``n_tokens``
+        tokens reads only the routed experts (``moe_routed``); none for a
+        config without experts."""
+        if not self.cfg.is_moe:
+            return {}
+        return {"moe_routed": routes_sparsely(self.cfg, n_tokens)}
 
     def _warm_of(self, si: int) -> _WarmCells:
         return self._warm.setdefault(self._devices[si], _WarmCells())
@@ -882,7 +891,7 @@ class ServeEngine:
             if rec is not None:
                 rec.end(span)
                 rec.tag(padded=n_pad, width=w)
-                span = rec.begin("engine.device")
+                span = rec.begin("engine.device", **self._moe_tag(n_pad))
             ids, state.pools = self._decode_paged(params, packed,
                                                   state.pools)
             # queue the ids' copy behind the step, so that they reach the
@@ -1159,7 +1168,8 @@ class ServeEngine:
             if rec is not None:
                 rec.end(span)
                 rec.tag(padded=n_pad, bucket=bucket)
-                span = rec.begin("engine.device")
+                span = rec.begin("engine.device",
+                                 **self._moe_tag(n_pad * bucket))
             t0 = time.monotonic()
             logits, cache, _ = jax.block_until_ready(
                 self._prefill(self._params_on(si), batch))

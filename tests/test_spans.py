@@ -367,3 +367,30 @@ def test_mirrored_annotations_agree_with_the_spans(served):
     assert checked >= 8 * STEPS
     assert not [e for e in events if e[0] in ("job", "server.queue",
                                               "job.first_token")]
+
+
+def test_moe_engine_tags_whether_a_call_reads_only_routed_experts(served):
+    """A MoE engine tags each device call ``moe_routed``: a one-row decode
+    call selects 2 of the reduced config's 4 experts and does, a prefill of
+    the whole prompt does not; a GQA engine tags none."""
+    assert not any("moe_routed" in s["attrs"]
+                   for s in named(served["spans"], "engine.device"))
+    cfg = get_config("deepseek_v2_lite_16b").reduced()
+    params = M.init_params(cfg, jax.random.PRNGKey(1))
+    eng = ServeEngine(cfg, params, max_seq=32, batching=True, paged=True,
+                      max_batch=1)
+    try:
+        assert eng.admit(_spec("m", 1)).admitted
+        rec = eng.enable_tracing()
+        eng.generate("m", served["prompt"], steps=STEPS)
+        eng.disable_tracing()
+        spans = list(rec.spans)
+    finally:
+        eng.close()
+    tags = {}
+    for phase in ("prefill", "decode"):
+        calls = {c["id"] for c in named(spans, "server.call", phase=phase)}
+        tags[phase] = [s["attrs"].get("moe_routed")
+                       for s in named(spans, "engine.device")
+                       if s["parent"] in calls]
+    assert tags == {"prefill": [False], "decode": [True] * STEPS}
